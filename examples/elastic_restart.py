@@ -20,6 +20,7 @@ from repro.data.pipeline import make_batch
 from repro.dist.constrain import use_mesh
 from repro.dist.sharding import batch_specs, named, param_specs
 from repro.ft import FaultInjector, ResilientLoop, StragglerMonitor
+from repro.launch.mesh import make_mesh
 from repro.nn.context import QuantContext
 from repro.train.step import build_train_step, init_state
 
@@ -70,11 +71,11 @@ def main():
     print(f"devices: {n}; checkpoints: {ckpt}")
 
     print("\nPhase 1: (n//2, 2) mesh with injected faults at steps 7, 12")
-    mesh1 = jax.make_mesh((max(n // 2, 1), min(2, n)), ("data", "model"))
+    mesh1 = make_mesh((max(n // 2, 1), min(2, n)), ("data", "model"))
     run_on_mesh(mesh1, ckpt, steps=15, fail_at=(7, 12))
 
     print("\nPhase 2: elastic restart on a (n, 1) mesh — same checkpoint")
-    mesh2 = jax.make_mesh((n, 1), ("data", "model"))
+    mesh2 = make_mesh((n, 1), ("data", "model"))
     run_on_mesh(mesh2, ckpt, steps=10)
 
     print("\nelastic restart OK")

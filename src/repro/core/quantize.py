@@ -30,6 +30,7 @@ __all__ = [
     "quantize_dynamic",
     "calibrate_scale",
     "ptq_params",
+    "ptq_leaf",
     "dequantize_params",
 ]
 
@@ -144,25 +145,33 @@ def ptq_params(params, policy, *,
     contraction axis": per-out-channel scales that also keep any leading
     layer-stack / expert axes, so stacked params remain scannable.
     """
+    return jax.tree_util.tree_map_with_path(
+        lambda p, l: ptq_leaf(p, l, policy, channel_axes=channel_axes,
+                              predicate=predicate), params)
+
+
+def ptq_leaf(path, leaf, policy, *,
+             channel_axes: Optional[Sequence[int]] = None,
+             predicate=_is_weight):
+    """:func:`ptq_params` for one leaf at tree ``path`` (key entries or
+    strings): its QTensor, or the leaf itself where it is not a weight
+    or the policy gives it no type."""
     from .precision import PrecisionPolicy  # local import to avoid a cycle
 
-    def quant_leaf(path, leaf):
-        if not predicate(path, leaf):
-            return leaf
-        if isinstance(policy, PrecisionPolicy):
-            qt = policy.resolve("/".join(str(p) for p in path)).weights
-        else:
-            qt = policy
-        if qt is None:
-            return leaf
-        if isinstance(qt, MiniFloatType):
-            return qt.quantize(leaf)
-        axes = (channel_axes if channel_axes is not None
-                else _weight_channel_axes(leaf.ndim))
-        return quantize_dynamic(leaf, qt, channel_axes=axes)
-
-    return jax.tree_util.tree_map_with_path(
-        lambda p, l: quant_leaf(tuple(_path_key(k) for k in p), l), params)
+    path = tuple(_path_key(k) for k in path)
+    if not predicate(path, leaf):
+        return leaf
+    if isinstance(policy, PrecisionPolicy):
+        qt = policy.resolve("/".join(str(p) for p in path)).weights
+    else:
+        qt = policy
+    if qt is None:
+        return leaf
+    if isinstance(qt, MiniFloatType):
+        return qt.quantize(leaf)
+    axes = (channel_axes if channel_axes is not None
+            else _weight_channel_axes(leaf.ndim))
+    return quantize_dynamic(leaf, qt, channel_axes=axes)
 
 
 def dequantize_params(qparams, dtype=jnp.float32):

@@ -13,7 +13,9 @@ carries multiple lowerings:
 Selection: explicit argument > ambient ``use_backend(...)`` context >
 global default.  Unknown (op, backend) pairs fall back to ``ref`` when
 ``allow_fallback`` — portability means degrading to the portable
-implementation, never failing.
+implementation, never failing.  :func:`resolve` names the lowering a
+call would get, so an entry point can print what it runs instead of
+degrading in silence.
 """
 
 from __future__ import annotations
@@ -22,8 +24,8 @@ import contextlib
 import threading
 from typing import Callable, Dict, Optional
 
-__all__ = ["register_op", "get_impl", "use_backend", "current_backend",
-           "set_default_backend", "list_ops"]
+__all__ = ["register_op", "get_impl", "resolve", "use_backend",
+           "current_backend", "set_default_backend", "list_ops"]
 
 _OPS: Dict[str, Dict[str, Callable]] = {}
 _state = threading.local()
@@ -58,18 +60,24 @@ def use_backend(backend: str):
         _state.backend = prev
 
 
-def get_impl(name: str, backend: Optional[str] = None, *,
-             allow_fallback: bool = True) -> Callable:
+def resolve(name: str, backend: Optional[str] = None, *,
+            allow_fallback: bool = True) -> str:
+    """The backend whose lowering ``get_impl(name, backend)`` returns."""
     if name not in _OPS:
         raise KeyError(f"op {name!r} is not registered")
     b = backend or current_backend()
     impls = _OPS[name]
     if b in impls:
-        return impls[b]
+        return b
     if allow_fallback and "ref" in impls:
-        return impls["ref"]
+        return "ref"
     raise KeyError(f"op {name!r} has no {b!r} lowering and fallback is off "
                    f"(available: {sorted(impls)})")
+
+
+def get_impl(name: str, backend: Optional[str] = None, *,
+             allow_fallback: bool = True) -> Callable:
+    return _OPS[name][resolve(name, backend, allow_fallback=allow_fallback)]
 
 
 def list_ops() -> Dict[str, list]:

@@ -21,15 +21,10 @@ from typing import Callable, Tuple
 import jax
 import jax.numpy as jnp
 
-try:  # jax >= 0.5 re-exports at top level
-    from jax import shard_map  # type: ignore[attr-defined]
-except ImportError:
-    from jax.experimental.shard_map import shard_map  # noqa: F401
-
 from ..core.qtypes import FixedPointType
 
 __all__ = ["quantized_psum", "quantized_psum_ef",
-           "make_pod_sharded_grad_fn", "shard_map"]
+           "make_pod_sharded_grad_fn"]
 
 
 def _round_trip(x: jnp.ndarray, qtype: FixedPointType) -> jnp.ndarray:
@@ -59,12 +54,12 @@ def make_pod_sharded_grad_fn(grad_fn: Callable, mesh, *,
                              in_specs, out_specs,
                              qtype: FixedPointType = None) -> Callable:
     """Wrap ``grad_fn(params, batch) -> (grads, metrics)`` in a shard_map
-    that is manual over the ``pod`` axis: each pod computes grads on its
-    batch shard, then the cross-pod mean runs through the quantized psum.
-    Remaining mesh axes stay automatic (GSPMD partitions inside the pod).
+    that is manual over the ``pod`` axis only: each pod computes grads on
+    its batch shard, then the cross-pod mean runs through the quantized
+    psum.  The remaining mesh axes stay automatic (GSPMD partitions
+    inside the pod).
     """
     npod = mesh.shape["pod"]
-    auto = frozenset(a for a in mesh.axis_names if a != "pod")
 
     def inner(params, batch):
         grads, metrics = grad_fn(params, batch)
@@ -80,9 +75,6 @@ def make_pod_sharded_grad_fn(grad_fn: Callable, mesh, *,
             lambda m: jax.lax.psum(m, "pod") * inv, metrics)
         return grads, metrics
 
-    try:
-        return shard_map(inner, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False, auto=auto)
-    except TypeError:  # newer shard_map: auto axes are implicit
-        return shard_map(inner, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs)
+    return jax.shard_map(inner, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, axis_names={"pod"},
+                         check_vma=False)

@@ -8,6 +8,12 @@ and ``"model"`` on a single-pod mesh, and to nothing at all when no mesh
 is active (single-host tests), in which case :func:`constrain` is the
 identity.  This is the de-specialized version of hard-coding a layout:
 the same forward function lowers correctly under every mesh shape.
+
+Pallas kernels are the exception to "GSPMD partitions everything": a
+Mosaic custom call cannot be partitioned automatically.
+:func:`kernel_map` runs such a call inside ``jax.shard_map`` over the
+active mesh, with per-operand logical specs, so each device runs the
+kernel on its own shard.
 """
 
 from __future__ import annotations
@@ -19,7 +25,8 @@ from typing import Optional
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-__all__ = ["use_mesh", "current_mesh", "constrain"]
+__all__ = ["use_mesh", "current_mesh", "constrain", "splits",
+           "kernel_map"]
 
 _state = threading.local()
 
@@ -80,3 +87,50 @@ def constrain(t: jax.Array, *labels) -> jax.Array:
     if all(a is None for a in spec):
         return t
     return jax.lax.with_sharding_constraint(t, NamedSharding(mesh, spec))
+
+
+def _label_size(label, mesh) -> int:
+    axis = _resolve_axis(label, mesh)
+    if axis is None:
+        return 1
+    axes = axis if isinstance(axis, tuple) else (axis,)
+    n = 1
+    for a in axes:
+        n *= mesh.shape[a]
+    return n
+
+
+def splits(label, dim: int) -> bool:
+    """Whether a ``dim``-sized axis splits evenly over ``label``'s mesh
+    axes (so a :func:`kernel_map` spec may name it).  False when no
+    mesh is active or the label spans one device — nothing to split."""
+    mesh = current_mesh()
+    if mesh is None:
+        return False
+    n = _label_size(label, mesh)
+    return n > 1 and dim % n == 0
+
+
+def kernel_map(fn, *args, in_specs, out_specs):
+    """``fn(*args)`` inside ``shard_map`` over the active mesh.
+
+    ``in_specs`` (one per arg) and ``out_specs`` are ``PartitionSpec``s
+    of *logical* labels (``"dp"``, ``"tp"``, None), resolved against the
+    mesh like :func:`constrain`; the caller checks divisibility with
+    :func:`splits` (a GQA kernel must split query and kv heads together,
+    which no per-operand guard can know).  Mesh axes an operand is not
+    split on see it replicated.  Without a mesh, or on a one-device
+    mesh, ``fn`` runs as is.
+    """
+    mesh = current_mesh()
+    if mesh is None or mesh.size == 1:
+        return fn(*args)
+
+    def res(spec):
+        return P(*(_resolve_axis(lb, mesh) for lb in spec))
+
+    outs = (tuple(res(s) for s in out_specs)
+            if isinstance(out_specs, (tuple, list)) else res(out_specs))
+    return jax.shard_map(fn, mesh=mesh,
+                         in_specs=tuple(res(s) for s in in_specs),
+                         out_specs=outs, check_vma=False)(*args)

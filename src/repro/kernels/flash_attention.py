@@ -31,8 +31,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
-
 __all__ = ["flash_attention_pallas", "paged_attention_pallas",
            "paged_attention_xla", "combine_splits", "choose_kv_split",
            "auto_pages_per_step", "get_cost_constants",
@@ -139,7 +137,7 @@ def flash_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
             pltpu.VMEM((bq, 1), jnp.float32),   # running denom
             pltpu.VMEM((bq, d), jnp.float32),   # output accumulator
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -259,7 +257,7 @@ def _paged_attention_unsplit(q: jnp.ndarray, k_pages: jnp.ndarray,
                           scale=scale),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(qf.shape, q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(jnp.asarray(block_tables, jnp.int32), jnp.asarray(qpos, jnp.int32),
@@ -605,7 +603,7 @@ def paged_attention_pallas(q: jnp.ndarray, k_pages: jnp.ndarray,
             jax.ShapeDtypeStruct((split, b, hkv, rows, 1), jnp.float32),
             jax.ShapeDtypeStruct((split, b, hkv, rows, 1), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

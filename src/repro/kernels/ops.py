@@ -2,10 +2,12 @@
 
 Every op is registered under the backends it supports; callers use these
 wrappers (or the registry directly) and never import a specific lowering.
-On CPU hosts the ``pallas`` backend automatically runs in interpret mode,
+Off the TPU the ``pallas`` backend runs its kernels in interpret mode,
 which executes the kernel body in Python — the portability story the paper
 asks for: one interface, ``ref`` everywhere, specialization where the
-hardware exists.
+hardware exists.  :func:`lowering` names what a call actually runs
+(``pallas``, ``pallas-interpret``, ``ref`` or ``xla-fusion``), so an
+entry point can say so rather than degrade quietly.
 """
 
 from __future__ import annotations
@@ -15,7 +17,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from ..core.registry import get_impl, register_op
+from ..core.registry import get_impl, register_op, resolve
 from ..core.tables import TableSpec
 from . import ref as _ref
 from .flash_attention import (flash_attention_pallas, paged_attention_pallas,
@@ -26,11 +28,33 @@ from .sampling import sample_tokens_fused
 from .speculative import verify_tokens_fused
 
 __all__ = ["lut_activation", "qmatmul", "attention", "paged_attention",
-           "sample_tokens", "verify_tokens"]
+           "sample_tokens", "verify_tokens", "on_tpu", "lowering",
+           "KERNEL_OPS"]
+
+#: ops whose ``pallas`` lowering is a ``pl.pallas_call``; the other ops'
+#: ``pallas`` registrations are XLA fusions (see sample_tokens below).
+KERNEL_OPS = ("attention", "paged_attention", "qmatmul", "lut_activation")
+
+
+def on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    return not on_tpu()
+
+
+def lowering(name: str, backend: Optional[str] = None) -> str:
+    """What ``name`` runs under ``backend`` on this host: ``pallas``
+    (compiled kernel), ``pallas-interpret`` (the kernel body in Python,
+    off the TPU), ``xla-fusion`` (a non-kernel specialized lowering) or
+    the registry backend it fell back to (``ref``, ``xla``)."""
+    b = resolve(name, backend)
+    if b != "pallas":
+        return b
+    if name not in KERNEL_OPS:
+        return "xla-fusion"
+    return "pallas" if on_tpu() else "pallas-interpret"
 
 
 # -- registrations ---------------------------------------------------------

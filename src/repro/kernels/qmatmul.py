@@ -49,10 +49,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
-
-from ..core.tables import TableSpec, get_table
-from .lut_activation import apply_table
+from ..core.tables import TableSpec
+from .lut_activation import apply_table, table_tile
 
 __all__ = ["qmatmul_pallas"]
 
@@ -153,10 +151,10 @@ def qmatmul_pallas(a_data: jnp.ndarray, b_data: jnp.ndarray,
         operands.append(brow)
         in_specs.append(pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)))
     if act_spec is not None:
-        table = jnp.asarray(get_table(act_spec).np_values)
+        table = table_tile(act_spec)
         operands.append(table)
         # the table is replicated into VMEM for every block
-        in_specs.append(pl.BlockSpec((act_spec.n,), lambda i, j, kk: (0,)))
+        in_specs.append(pl.BlockSpec(table.shape, lambda i, j, kk: (0, 0)))
 
     out = pl.pallas_call(
         functools.partial(_kernel, k_steps=grid[2],
@@ -167,7 +165,7 @@ def qmatmul_pallas(a_data: jnp.ndarray, b_data: jnp.ndarray,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*operands)

@@ -40,9 +40,14 @@ from ..models.config import ModelConfig
 from ..nn.context import QuantContext
 from ..optim import cosine_warmup
 from .mesh import make_production_mesh
-from .roofline import roofline
+from .roofline import peaks, roofline
 from .specs import (SHAPES, applicable, input_specs, microbatches_for,
                     state_struct)
+
+
+#: the chip the production meshes stand for (16x16 = one v5e pod); the
+#: placeholder host devices have no kind of their own
+TARGET_KIND = "TPU v5 lite"
 
 
 def _ctx(cfg: ModelConfig, overrides=None) -> QuantContext:
@@ -155,7 +160,7 @@ def run_cell(arch: str, shape: str, mesh_kind: str, *, ctx_overrides=None,
     rep = roofline(arch=arch, shape=shape, mesh=mesh_kind, chips=chips,
                    cost=cost, hlo_text=hlo,
                    model_flops=model_flops_for(cfg, shape),
-                   memory_analysis=mem_d)
+                   hw=peaks(TARGET_KIND), memory_analysis=mem_d)
     out = rep.to_json()
     from ..dist.options import flags as _flags
     out.update(status="ok", lower_s=round(t_lower, 1),

@@ -1,9 +1,9 @@
 """Roofline term derivation from compiled dry-run artifacts.
 
-TPU v5e hardware model (per chip):
-    peak bf16 compute  197 TFLOP/s
-    HBM bandwidth      819 GB/s
-    ICI                ~50 GB/s per link
+Hardware peaks come from :data:`PEAKS`, one row per ``device_kind`` (as
+``jax.devices()[0].device_kind`` names the chip) with its source; a
+device that is not in the table raises rather than borrowing another
+chip's numbers.
 
 Terms (seconds, per step, per chip — the SPMD module is per-device, so
 ``cost_analysis`` flops/bytes are already per-chip):
@@ -26,7 +26,8 @@ import json
 import re
 from typing import Dict, List, Optional
 
-__all__ = ["HW", "parse_collectives", "roofline", "RooflineReport"]
+__all__ = ["HW", "PEAKS", "peaks", "parse_collectives", "roofline",
+           "RooflineReport"]
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -37,10 +38,30 @@ _DTYPE_BYTES = {
 
 @dataclasses.dataclass(frozen=True)
 class HW:
-    peak_flops: float = 197e12       # bf16 / chip
-    hbm_bw: float = 819e9            # bytes/s / chip
-    link_bw: float = 50e9            # bytes/s / link
-    links: int = 4                   # ICI links per chip engaged
+    peak_flops: float                # bf16 FLOP/s per chip
+    hbm_bw: float                    # HBM bytes/s per chip
+    link_bw: float                   # ICI bytes/s per link
+    links: int                       # ICI links per chip engaged
+    source: str = ""
+
+
+#: per-chip peaks keyed by ``device_kind``
+PEAKS = {
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB HBM
+    # at 819 GB/s, 1,600 Gbit/s of interchip interconnect per chip
+    # (= 4 links x 50 GB/s)
+    "TPU v5 lite": HW(peak_flops=197e12, hbm_bw=819e9, link_bw=50e9,
+                      links=4, source="cloud.google.com/tpu/docs/v5e"),
+}
+
+
+def peaks(device_kind: str) -> HW:
+    """The peaks row for ``device_kind``; KeyError for an unknown chip."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no peaks for device kind {device_kind!r} "
+                       f"(known: {sorted(PEAKS)})") from None
 
 
 _COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
@@ -119,6 +140,7 @@ class RooflineReport:
     memory_s: float
     collective_s: float
     model_flops: float           # 6·N·D or 2·N·D (global)
+    peak_flops: float            # per chip, from the peaks row used
     collectives: List[Dict] = dataclasses.field(default_factory=list)
     memory_analysis: Optional[Dict] = None
     raw_cost_analysis: Optional[Dict] = None
@@ -142,8 +164,7 @@ class RooflineReport:
     @property
     def mfu(self) -> float:
         """Model-flops utilization at the roofline step time."""
-        hw = HW()
-        denom = self.step_time * self.chips * hw.peak_flops
+        denom = self.step_time * self.chips * self.peak_flops
         return self.model_flops / denom if denom else 0.0
 
     def to_json(self) -> Dict:
@@ -156,8 +177,8 @@ class RooflineReport:
 
 def roofline(*, arch: str, shape: str, mesh: str, chips: int,
              cost: Dict, hlo_text: str, model_flops: float,
-             memory_analysis: Optional[Dict] = None,
-             hw: HW = HW()) -> RooflineReport:
+             hw: HW, memory_analysis: Optional[Dict] = None
+             ) -> RooflineReport:
     """Roofline terms from the loop-corrected HLO analysis.
 
     ``cost`` (raw ``compiled.cost_analysis()``) is recorded alongside for
@@ -176,7 +197,7 @@ def roofline(*, arch: str, shape: str, mesh: str, chips: int,
         compute_s=a.flops / hw.peak_flops,
         memory_s=a.bytes / hw.hbm_bw,
         collective_s=a.wire_bytes / (hw.links * hw.link_bw),
-        model_flops=model_flops,
+        model_flops=model_flops, peak_flops=hw.peak_flops,
         collectives=a.collectives,
         memory_analysis=memory_analysis,
     )

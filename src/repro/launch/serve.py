@@ -103,12 +103,13 @@ from ..models.api import (copy_pages_fn, get_family, init_paged_cache_fn,
                           invalidate_fn, merge_slot_fn, set_block_table,
                           spec_restore_fn, spec_state_fn,
                           supports_chunked_prefill)
-from ..nn.context import QuantContext
+from ..nn.context import QuantContext, lowerings
 from ..train.step import (build_decode_loop, build_prefill_step,
                           build_serve_step, build_spec_decode_loop)
 from .lifecycle import (PriorityClass, RequestStatus, coerce_priority,
                         normalize_class_quotas, normalize_slo_targets,
                         request_row, validate_request)
+from .compile_cache import configure_compile_cache
 from .lifecycle import now as _now
 from .mesh import make_local_mesh
 from .paging import PageAllocator
@@ -2367,13 +2368,56 @@ def quantize_for_serving(params, ctx: QuantContext):
 
     Weight matrices become QTensor (per-out-channel scales) per the
     context's precision policy; ``linear()`` then consumes them with
-    zero per-forward weight-quantization work.
+    zero per-forward weight-quantization work.  Each leaf is quantized
+    by its own jitted program and each float weight is deleted once its
+    quantized copy exists, so the device never holds both models: the
+    tree passed in is consumed.  Leaves that stay float are returned
+    as they are.  Each quantized leaf is waited for before the next is
+    dispatched: the device allocates a program's outputs when it is
+    enqueued but frees a deleted input only when its readers finish, so
+    with cached compiles the dispatch ran ahead and held most of both
+    models at once.
     """
-    from ..core.quantize import ptq_params
-    return ptq_params(params, ctx.policy)
+    from ..core.qtypes import QTensor
+    from ..core.quantize import ptq_leaf
+
+    def one(path, leaf):
+        q = jax.jit(lambda x: ptq_leaf(path, x, ctx.policy))(leaf)
+        if isinstance(q, QTensor):
+            jax.block_until_ready(q)
+            leaf.delete()
+        return q
+
+    return jax.tree_util.tree_map_with_path(one, params)
 
 
-def main(argv=None):
+def serving_params(cfg, ctx: QuantContext, mesh, *, seed: int):
+    """Random parameters for ``cfg`` from ``seed``, made on the device.
+
+    They are created by one jitted program straight into their mesh
+    shardings in ``ctx.param_dtype`` — no float32 copy of the model is
+    ever materialized unless that is the serving dtype — and, under
+    ``mode="int8"``, quantized leaf by leaf with each float leaf freed
+    (:func:`quantize_for_serving`).
+    """
+    fam = get_family(cfg)
+    key = jax.random.PRNGKey(seed)
+
+    def init(k):
+        return fam.init(k, cfg, dtype=ctx.param_dtype)
+
+    shapes = jax.eval_shape(init, key)
+    params = jax.jit(init, out_shardings=named(
+        param_specs(shapes, mesh), mesh))(key)
+    if ctx.mode == "int8":
+        # the fused pipeline's first leg: weights quantized ONCE here
+        params = quantize_for_serving(params, ctx)
+        params = jax.device_put(params, named(param_specs(params, mesh),
+                                              mesh))
+    return params
+
+
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
@@ -2387,7 +2431,12 @@ def main(argv=None):
     ap.add_argument("--qbits", type=int, default=8)
     ap.add_argument("--lut", action="store_true")
     ap.add_argument("--f32", action="store_true")
-    ap.add_argument("--reuse-factor", type=int, default=1)
+    ap.add_argument("--reuse-factor", type=int, default=8,
+                    help="layer-scan unroll = max(8 // reuse_factor, 1); "
+                         "the default keeps the layer loop rolled: "
+                         "unrolled by 8, XLA copies 8 layers of weights "
+                         "per loop iteration, which took the yi-6b "
+                         "decode loop to 16.25 GB, past one v5e's HBM")
     ap.add_argument("--kv-bits", type=int, default=None, choices=[8],
                     help="int8 KV cache (per-token scales)")
     ap.add_argument("--prefill-chunk", type=int, default=16,
@@ -2518,110 +2567,159 @@ def main(argv=None):
                          "then never evict the realtime working set")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    if args.spec_draft:
+        args.spec = True                        # a drafter implies --spec
+    return args
 
+
+def setup(args, devices=None):
+    """(cfg, ctx, mesh) for parsed serve arguments.
+
+    The kernels are the serving backend on a TPU (``pallas``); elsewhere
+    the portable ``ref`` lowerings run, and :func:`lowerings_line` says
+    so.  ``devices`` restricts the mesh (default: every device).
+    """
+    from ..kernels.ops import on_tpu
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = cfg.smoke()
-    ctx = build_ctx(args)
-    mesh = make_local_mesh(model=args.model_parallel)
-    fam = get_family(cfg)
+    ctx = build_ctx(args, backend="pallas" if on_tpu() else "ref")
+    mesh = make_local_mesh(model=args.model_parallel, devices=devices)
+    return cfg, ctx, mesh
 
+
+def _first_pages(tree):
+    """The first ``pages`` subtree of a serving cache (None: dense)."""
+    if isinstance(tree, dict):
+        if "pages" in tree:
+            return tree["pages"]
+        for sub in tree.values():
+            found = _first_pages(sub)
+            if found is not None:
+                return found
+    return None
+
+
+def lowerings_line(eng) -> str:
+    """One line naming the lowering every op of this engine resolved to
+    (:func:`repro.nn.context.lowerings`)."""
+    low = lowerings(eng.ctx, pages=_first_pages(eng.cache), spec=eng.spec)
+    if eng.cfg.family == "ssm":         # no attention layer to lower
+        low = {k: v for k, v in low.items() if "attention" not in k}
+    return "lowerings: " + " ".join(f"{k}={v}" for k, v in low.items())
+
+
+def build_engine(args, cfg, ctx, mesh, params):
+    """``(engine, fleet)`` for parsed serve arguments; ``fleet`` is None
+    unless ``--replicas`` > 1 or ``--standby-dir`` asks for one (the
+    engine is then its first replica).  Call under ``use_mesh(mesh)``."""
+    spec_draft = None
+    if args.spec_draft:
+        d_cfg = get_config(args.spec_draft)
+        if args.smoke:
+            d_cfg = d_cfg.smoke()
+        d_params = get_family(d_cfg).init(
+            jax.random.PRNGKey(args.seed + 1), d_cfg)
+        spec_draft = (d_cfg, d_params, ctx)
+    max_len = args.prompt_len + args.gen_len + 1
+
+    def knob(v):
+        return "auto" if v == "auto" else int(v)
+
+    eng_kw = dict(batch=args.batch,
+                  max_len=max_len, kv_bits=args.kv_bits,
+                  prefill_chunk=args.prefill_chunk, seed=args.seed,
+                  paged=args.paged, page_size=args.page_size,
+                  num_pages=args.num_pages,
+                  kv_split=knob(args.kv_split),
+                  pages_per_step=knob(args.pages_per_step),
+                  prefix_cache=args.prefix_cache,
+                  autotune=args.autotune,
+                  spec=args.spec,
+                  spec_k=args.spec_k, spec_draft=spec_draft,
+                  spec_ngram=args.spec_ngram, preempt=args.preempt,
+                  shed_threshold=args.shed_threshold,
+                  class_quotas=_parse_class_quotas(args.class_quota),
+                  slo_targets=(
+                      {"realtime": {"ttft_s": args.slo_ttft_s,
+                                    "tok_per_s": args.slo_tok_per_s}}
+                      if (args.slo_ttft_s is not None
+                          or args.slo_tok_per_s is not None) else None),
+                  durable_dir=args.durable_dir,
+                  snapshot_every=args.snapshot_every)
+
+    def make_engine(**over):
+        return Engine(cfg, ctx, params, mesh, **dict(eng_kw, **over))
+
+    if args.replicas > 1 or args.standby_dir is not None:
+        from .fleet import Fleet
+        # the fleet owns durability (primary journals under
+        # --standby-dir); replicas sharing one --durable-dir would
+        # clobber each other's journal
+        eng_kw["durable_dir"] = None
+        fleet = Fleet(make_engine, args.replicas,
+                      standby_dir=args.standby_dir)
+        return fleet.replicas[0], fleet
+    return make_engine(), None
+
+
+def make_prompts(cfg, args) -> List[np.ndarray]:
+    """``--requests`` synthetic prompts of ``--prompt-len`` tokens."""
+    src = SyntheticLM(cfg.vocab, seed=args.seed)
+    return [src.tokens(i, 1, args.prompt_len)[0, :-1]
+            for i in range(args.requests)]
+
+
+def drive(eng, fleet, prompts, args) -> tuple:
+    """Serve ``prompts`` to completion; returns ``(engine, generated
+    tokens, decode block)`` — the engine, because a fleet promotion may
+    have swapped it."""
+    # explicit flag > autotuner-resolved block > the legacy default
+    block = max(1, args.decode_block if args.decode_block is not None
+                else (eng.decode_block or 8))
+    gen_tokens = 0
+    # continuous batching through the admission queue: every request
+    # is submitted up front; step_many retires finished slots and
+    # admits whatever the freed lanes (and, paged, freed pages)
+    # cover, one block's latency after they free up
+    if fleet is not None:
+        for p in prompts:
+            fleet.submit(p, gen_len=args.gen_len,
+                         temperature=args.temperature,
+                         top_k=args.top_k,
+                         deadline_s=args.deadline_s,
+                         priority=args.priority_class)
+        fleet.try_admit()
+        fleet.drain(block=block)
+        eng = fleet.replicas[0]     # promotion may have swapped it
+        gen_tokens = sum(
+            s["gen_tokens"] for s in fleet.stats()["per_replica"]
+            if s is not None)
+    else:
+        for p in prompts:
+            eng.submit(p, gen_len=args.gen_len,
+                       temperature=args.temperature, top_k=args.top_k,
+                       deadline_s=args.deadline_s,
+                       priority=args.priority_class)
+        eng.try_admit()
+        while eng.live.any() or eng.waiting:
+            _, block_live = eng.step_many(block)
+            gen_tokens += int(block_live.sum())
+        eng.retire_finished()
+    return eng, gen_tokens, block
+
+
+def main(argv=None):
+    args = parse_args(argv)
+    configure_compile_cache()
+    cfg, ctx, mesh = setup(args)
     with use_mesh(mesh):
-        params = fam.init(jax.random.PRNGKey(args.seed), cfg)
-        if args.quant == "int8":
-            # the fused pipeline's first leg: weights quantized ONCE here
-            params = quantize_for_serving(params, ctx)
-        p_sh = named(param_specs(params, mesh), mesh)
-        params = jax.device_put(params, p_sh)
-        if args.spec_draft:
-            args.spec = True                    # a drafter implies --spec
-        spec_draft = None
-        if args.spec_draft:
-            d_cfg = get_config(args.spec_draft)
-            if args.smoke:
-                d_cfg = d_cfg.smoke()
-            d_params = get_family(d_cfg).init(
-                jax.random.PRNGKey(args.seed + 1), d_cfg)
-            spec_draft = (d_cfg, d_params, ctx)
-        max_len = args.prompt_len + args.gen_len + 1
-
-        def knob(v):
-            return "auto" if v == "auto" else int(v)
-
-        eng_kw = dict(batch=args.batch,
-                      max_len=max_len, kv_bits=args.kv_bits,
-                      prefill_chunk=args.prefill_chunk, seed=args.seed,
-                      paged=args.paged, page_size=args.page_size,
-                      num_pages=args.num_pages,
-                      kv_split=knob(args.kv_split),
-                      pages_per_step=knob(args.pages_per_step),
-                      prefix_cache=args.prefix_cache,
-                      autotune=args.autotune,
-                      spec=args.spec,
-                      spec_k=args.spec_k, spec_draft=spec_draft,
-                      spec_ngram=args.spec_ngram, preempt=args.preempt,
-                      shed_threshold=args.shed_threshold,
-                      class_quotas=_parse_class_quotas(args.class_quota),
-                      slo_targets=(
-                          {"realtime": {"ttft_s": args.slo_ttft_s,
-                                        "tok_per_s": args.slo_tok_per_s}}
-                          if (args.slo_ttft_s is not None
-                              or args.slo_tok_per_s is not None) else None),
-                      durable_dir=args.durable_dir,
-                      snapshot_every=args.snapshot_every)
-
-        def make_engine(**over):
-            return Engine(cfg, ctx, params, mesh, **dict(eng_kw, **over))
-
-        fleet = None
-        if args.replicas > 1 or args.standby_dir is not None:
-            from .fleet import Fleet
-            # the fleet owns durability (primary journals under
-            # --standby-dir); replicas sharing one --durable-dir would
-            # clobber each other's journal
-            eng_kw["durable_dir"] = None
-            fleet = Fleet(make_engine, args.replicas,
-                          standby_dir=args.standby_dir)
-            eng = fleet.replicas[0]
-        else:
-            eng = make_engine()
-
-        src = SyntheticLM(cfg.vocab, seed=args.seed)
-        prompts = [src.tokens(i, 1, args.prompt_len)[0, :-1]
-                   for i in range(args.requests)]
-        # explicit flag > autotuner-resolved block > the legacy default
-        block = max(1, args.decode_block if args.decode_block is not None
-                    else (eng.decode_block or 8))
+        params = serving_params(cfg, ctx, mesh, seed=args.seed)
+        eng, fleet = build_engine(args, cfg, ctx, mesh, params)
+        print(lowerings_line(eng))
+        prompts = make_prompts(cfg, args)
         t0 = time.perf_counter()
-        gen_tokens = 0
-        # continuous batching through the admission queue: every request
-        # is submitted up front; step_many retires finished slots and
-        # admits whatever the freed lanes (and, paged, freed pages)
-        # cover, one block's latency after they free up
-        if fleet is not None:
-            for p in prompts:
-                fleet.submit(p, gen_len=args.gen_len,
-                             temperature=args.temperature,
-                             top_k=args.top_k,
-                             deadline_s=args.deadline_s,
-                             priority=args.priority_class)
-            fleet.try_admit()
-            fleet.drain(block=block)
-            eng = fleet.replicas[0]     # promotion may have swapped it
-            gen_tokens = sum(
-                s["gen_tokens"] for s in fleet.stats()["per_replica"]
-                if s is not None)
-        else:
-            for p in prompts:
-                eng.submit(p, gen_len=args.gen_len,
-                           temperature=args.temperature, top_k=args.top_k,
-                           deadline_s=args.deadline_s,
-                           priority=args.priority_class)
-            eng.try_admit()
-            while eng.live.any() or eng.waiting:
-                _, block_live = eng.step_many(block)
-                gen_tokens += int(block_live.sum())
-            eng.retire_finished()
+        eng, gen_tokens, block = drive(eng, fleet, prompts, args)
         dt = time.perf_counter() - t0
         paged_note = (f" paged(ps={eng.allocator.page_size},"
                       f"pages={eng.allocator.num_pages},"

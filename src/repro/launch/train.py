@@ -32,17 +32,22 @@ from ..ft import FaultInjector, ResilientLoop, StragglerMonitor
 from ..nn.context import QuantContext
 from ..optim import cosine_warmup
 from ..train.step import build_train_step, init_state
+from .compile_cache import configure_compile_cache
 from .mesh import make_local_mesh
 
 
-def build_ctx(args) -> QuantContext:
+def build_ctx(args, backend=None) -> QuantContext:
+    """The context for parsed command-line flags.  ``--f32`` sets both
+    the compute and the parameter dtype (bf16 otherwise); ``backend``
+    selects the kernel lowerings (None = the registry default)."""
     policy = PrecisionPolicy()
     if args.quant != "none":
         qt = FixedPointType(args.qbits, max(args.qbits // 2, 2))
         policy = PrecisionPolicy.uniform(qt)
+    dtype = jnp.float32 if args.f32 else jnp.bfloat16
     return QuantContext(mode=args.quant, policy=policy, use_lut=args.lut,
-                        compute_dtype=jnp.float32 if args.f32 else jnp.bfloat16,
-                        reuse_factor=args.reuse_factor)
+                        compute_dtype=dtype, param_dtype=dtype,
+                        reuse_factor=args.reuse_factor, backend=backend)
 
 
 def main(argv=None):
@@ -72,6 +77,7 @@ def main(argv=None):
 
     logging.basicConfig(level=logging.INFO,
                         format="%(asctime)s %(name)s %(message)s")
+    configure_compile_cache()
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = cfg.smoke()

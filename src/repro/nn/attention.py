@@ -32,10 +32,12 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from jax.sharding import PartitionSpec as P
+
 from ..core.qtypes import QTensor
-from ..dist.constrain import constrain
+from ..dist.constrain import constrain, kernel_map, splits
 from .activations import softmax
-from .context import DEFAULT_CTX, QuantContext
+from .context import DEFAULT_CTX, QuantContext, kernel_path
 from .linear import linear, linear_init
 from .norms import rmsnorm, rmsnorm_init
 from .rope import apply_rope
@@ -70,7 +72,24 @@ def _constrain_heads(t: jnp.ndarray, role: str = "q") -> jnp.ndarray:
 
 __all__ = ["AttnDims", "gqa_init", "gqa_apply", "gqa_cache_spec",
            "gqa_paged_cache_spec", "gqa_project_kv", "MLADims", "mla_init",
-           "mla_apply", "mla_cache_spec", "mla_paged_cache_spec"]
+           "mla_apply", "mla_cache_spec", "mla_paged_cache_spec",
+           "paged_kernel_reads"]
+
+
+def paged_kernel_reads(ctx: QuantContext, pages, *,
+                       causal: bool = True) -> bool:
+    """Whether attention over a paged cache whose page pools are
+    ``pages`` (a layer's ``cache["pages"]``, or its keys) runs the
+    block-table Pallas kernel under ``ctx``; otherwise the pages are
+    gathered into a contiguous view and attended by einsum.
+
+    The kernel reads float GQA head pages (``k``/``v``) under a causal
+    mask only: int8 pages (with ``k_scale``/``v_scale``) and MLA latent
+    pages (``ckv``/``krope``) are always gathered.  The attention modules
+    dispatch on this and :func:`repro.nn.context.lowerings` reports it.
+    """
+    return (set(pages) == {"k", "v"} and causal
+            and (kernel_path(ctx) or ctx.force_paged_kernel))
 
 
 def gqa_project_kv(p, kv_src: jnp.ndarray, d: "AttnDims",
@@ -206,6 +225,44 @@ def _quantize_kv(u: jnp.ndarray):
     scale = jnp.maximum(amax, 1e-6) / 127.0
     q = jnp.clip(jnp.round(u.astype(jnp.float32) / scale), -127, 127)
     return q.astype(jnp.int8), scale.astype(jnp.bfloat16)
+
+
+def _head_specs(b: int, hkv: int):
+    """Logical (batch, head) split of a kernel's q/k/v operands: batch
+    over the data axes and heads over the model axis where each divides.
+    Query and kv heads split together (or not at all), so every shard
+    keeps whole GQA groups."""
+    return ("dp" if splits("dp", b) else None,
+            "tp" if splits("tp", hkv) else None)
+
+
+def _flash_kernel(q, k, v, *, causal: bool, softmax_scale=None):
+    """Flash Pallas kernel, run per (batch, head) shard of the mesh."""
+    from ..kernels.ops import attention as flash
+    dp, tp = _head_specs(q.shape[0], k.shape[1])
+    s = P(dp, tp)
+    return kernel_map(
+        lambda q, k, v: flash(q, k, v, causal=causal,
+                              softmax_scale=softmax_scale, backend="pallas"),
+        q, k, v, in_specs=(s, s, s), out_specs=s)
+
+
+def _paged_kernel(q, k_pages, v_pages, bt, pos, ctx: QuantContext):
+    """Block-table paged Pallas kernel, run per (batch, head) shard.
+
+    The page pool is replicated (``dist.sharding.cache_specs``): each
+    shard reads its own kv heads of it and the block-table rows of its
+    own batch lanes.
+    """
+    from ..kernels.ops import paged_attention
+    dp, tp = _head_specs(q.shape[0], k_pages.shape[1])
+    return kernel_map(
+        lambda q, kp, vp, bt, pos: paged_attention(
+            q, kp, vp, bt, pos, kv_split=ctx.kv_split,
+            pages_per_step=ctx.pages_per_step, backend="pallas"),
+        q, k_pages, v_pages, bt, pos,
+        in_specs=(P(dp, tp), P(None, tp), P(None, tp), P(dp), P(dp)),
+        out_specs=P(dp, tp))
 
 
 def _einsum_attention(q, k, v, *, causal: bool, ctx: QuantContext,
@@ -362,7 +419,18 @@ def gqa_apply(p, x: jnp.ndarray, d: AttnDims, ctx: QuantContext = DEFAULT_CTX,
         zeros = jnp.zeros((b,), jnp.int32) if cache_pos is None else cache_pos
         page, row = _page_coords(bt, zeros, s, pages["k"].shape[2])
         cd = ctx.compute_dtype
-        if "k_scale" in pages:          # int8 pages + scale pages
+        if paged_kernel_reads(ctx, pages, causal=d.causal):
+            # TPU path: block-table-indexed flash kernel — pages are
+            # DMA'd on demand, the contiguous view never exists.
+            # ctx.kv_split / ctx.pages_per_step ride through here:
+            # the kernel partitions the block table into parallel
+            # flash-decoding lanes (None = cost-model auto).
+            # ``force_paged_kernel`` drives the same kernel in
+            # interpret mode off-TPU (CPU conformance suites).
+            pages = {"k": _paged_write(pages["k"], page, row, k),
+                     "v": _paged_write(pages["v"], page, row, v)}
+            y = _paged_kernel(q, pages["k"], pages["v"], bt, zeros, ctx)
+        elif "k_scale" in pages:        # int8 pages + scale pages
             kq, ks = _quantize_kv(k)
             vq, vs = _quantize_kv(v)
             pages = {"k": _paged_write(pages["k"], page, row, kq),
@@ -378,28 +446,10 @@ def gqa_apply(p, x: jnp.ndarray, d: AttnDims, ctx: QuantContext = DEFAULT_CTX,
         else:
             pages = {"k": _paged_write(pages["k"], page, row, k),
                      "v": _paged_write(pages["v"], page, row, v)}
-            use_kernel = (ctx.backend == "pallas"
-                          and jax.default_backend() == "tpu") \
-                or ctx.force_paged_kernel
-            if use_kernel and d.causal:
-                # TPU path: block-table-indexed flash kernel — pages are
-                # DMA'd on demand, the contiguous view never exists.
-                # ctx.kv_split / ctx.pages_per_step ride through here:
-                # the kernel partitions the block table into parallel
-                # flash-decoding lanes (None = cost-model auto).
-                # ``force_paged_kernel`` drives the same kernel in
-                # interpret mode off-TPU (CPU conformance suites).
-                from ..kernels.ops import paged_attention
-                y = paged_attention(q, pages["k"], pages["v"], bt, zeros,
-                                    kv_split=ctx.kv_split,
-                                    pages_per_step=ctx.pages_per_step,
-                                    backend="pallas")
-            else:
-                ck = _paged_gather(pages["k"], bt)
-                cv = _paged_gather(pages["v"], bt)
-                mask = _cache_mask(zeros, s, ck.shape[2], d.causal)
-                y = _einsum_attention(q, ck, cv, causal=False, ctx=ctx,
-                                      mask=mask)
+            ck = _paged_gather(pages["k"], bt)
+            cv = _paged_gather(pages["v"], bt)
+            mask = _cache_mask(zeros, s, ck.shape[2], d.causal)
+            y = _einsum_attention(q, ck, cv, causal=False, ctx=ctx, mask=mask)
         new_cache = {"pages": pages, "block_table": bt}
     elif cache is not None:
         # decode (s == 1) or chunked prefill: write K/V at cache_pos
@@ -438,11 +488,10 @@ def gqa_apply(p, x: jnp.ndarray, d: AttnDims, ctx: QuantContext = DEFAULT_CTX,
         y = _einsum_attention(q, ck, cv, causal=False, ctx=ctx, mask=mask)
     else:
         causal = d.causal and kv_input is None
-        if ctx.backend == "pallas" and jax.default_backend() == "tpu":
-            # TPU execution path: the flash Pallas kernel (wrapped in
-            # shard_map over batch/head shards by the serving launcher)
-            from ..kernels.ops import attention as flash
-            y = flash(q, k, v, causal=causal, backend=ctx.backend)
+        if kernel_path(ctx):
+            # TPU execution path: the flash Pallas kernel, run inside
+            # shard_map over the batch/head shards (_flash_kernel)
+            y = _flash_kernel(q, k, v, causal=causal)
         elif max(s, skv) > CHUNK_THRESHOLD:
             y = _chunked_attention(q, k, v, causal=causal, ctx=ctx)
         else:
@@ -573,13 +622,12 @@ def mla_apply(p, x: jnp.ndarray, d: MLADims, ctx: QuantContext = DEFAULT_CTX,
         qT = _constrain_heads(qT, "q")
         kT = _constrain_heads(kT, "kv")
         vT = _constrain_heads(vT, "kv")
-        if ctx.backend == "pallas" and jax.default_backend() == "tpu":
-            from ..kernels.ops import attention as flash
+        if kernel_path(ctx):
             # flash kernel wants dv == dqk: zero-pad V and slice after
             pad = d.qk_dim - d.v_head_dim
             vp = jnp.pad(vT, ((0, 0), (0, 0), (0, 0), (0, pad)))
-            y = flash(qT, kT, vp, causal=True,
-                      softmax_scale=d.qk_dim ** -0.5, backend=ctx.backend)
+            y = _flash_kernel(qT, kT, vp, causal=True,
+                              softmax_scale=d.qk_dim ** -0.5)
             y = y[..., :d.v_head_dim]
         elif s > CHUNK_THRESHOLD:
             y = _chunked_attention(qT, kT, vT, causal=True, ctx=ctx)
@@ -592,8 +640,10 @@ def mla_apply(p, x: jnp.ndarray, d: MLADims, ctx: QuantContext = DEFAULT_CTX,
     zeros = jnp.zeros((b,), jnp.int32) if cache_pos is None else cache_pos
     if "pages" in cache:
         # paged latent: scatter this chunk's rows into the slot's pages,
-        # score against the gathered logical view (write-before-attend)
+        # score against the gathered logical view (write-before-attend);
+        # the block-table kernel does not read latent pages
         pages, bt = cache["pages"], cache["block_table"]
+        assert not paged_kernel_reads(ctx, pages)
         page, row = _page_coords(bt, zeros, s, pages["ckv"].shape[1])
         pages = {"ckv": pages["ckv"].at[page, row].set(
                      ckv.astype(pages["ckv"].dtype)),

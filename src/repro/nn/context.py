@@ -17,7 +17,7 @@ import jax.numpy as jnp
 from ..core.precision import PrecisionPolicy
 from ..core.qtypes import FixedPointType
 
-__all__ = ["QuantContext", "DEFAULT_CTX"]
+__all__ = ["QuantContext", "DEFAULT_CTX", "kernel_path", "lowerings"]
 
 _MODES = ("none", "fake", "int8")
 
@@ -41,6 +41,8 @@ class QuantContext:
       reuse_factor, 1)) and kernel block K is divided accordingly.
     backend:
       kernel backend override (None = registry default; "ref" | "pallas").
+      The model takes its Pallas kernel paths only where
+      :func:`kernel_path` holds; :func:`lowerings` says what runs.
     """
 
     mode: str = "none"
@@ -91,3 +93,42 @@ class QuantContext:
 
 
 DEFAULT_CTX = QuantContext()
+
+
+def kernel_path(ctx: QuantContext) -> bool:
+    """Whether the attention modules call their Pallas kernels under
+    ``ctx`` on this host (``pallas`` backend on a TPU); elsewhere they
+    take the einsum path, which :func:`lowerings` reports."""
+    from ..kernels.ops import on_tpu
+    return ctx.backend == "pallas" and on_tpu()
+
+
+def lowerings(ctx: QuantContext, *, pages=None, spec: bool = False) -> dict:
+    """op name -> the lowering a model run under ``ctx`` resolves it to.
+
+    Only the ops such a run calls are listed: ``attention`` (prompt
+    forward without a cache), ``paged_attention`` (given ``pages``, one
+    layer's page pools or their keys), ``qmatmul`` (``mode="int8"``),
+    ``lut_activation`` (``use_lut``), ``sample_tokens`` and, with
+    ``spec``, ``verify_tokens``.  Values come from
+    :func:`repro.kernels.ops.lowering`, except where the model bypasses
+    the op: ``einsum`` (attention off the kernel path) and
+    ``einsum-gather`` (paged attention that gathers its pages, as
+    :func:`repro.nn.attention.paged_kernel_reads` decides).
+    """
+    from ..kernels.ops import lowering
+    from .attention import paged_kernel_reads
+    out = {"attention": lowering("attention", ctx.backend)
+           if kernel_path(ctx) else "einsum"}
+    if pages is not None:
+        out["paged_attention"] = (lowering("paged_attention", "pallas")
+                                  if paged_kernel_reads(ctx, pages)
+                                  else "einsum-gather")
+    if ctx.mode == "int8":
+        out["qmatmul"] = lowering("qmatmul", ctx.backend)
+    if ctx.use_lut:
+        out["lut_activation"] = lowering("lut_activation", ctx.backend)
+    out["sample_tokens"] = lowering("sample_tokens", ctx.backend)
+    if spec:
+        out["verify_tokens"] = lowering("verify_tokens", ctx.backend)
+    return out

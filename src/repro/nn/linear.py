@@ -37,8 +37,11 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from ..core.precision import LayerPrecision
+from ..core.registry import resolve
+from ..dist.constrain import kernel_map, splits
 from ..core.quantize import calibrate_scale, fake_quant
 from ..core.qtypes import FixedPointType, MiniFloatType, QTensor
 from ..core.tables import GATED_FORMS, TableSpec
@@ -81,15 +84,30 @@ def _int8_matmul(x2: jnp.ndarray, wq: jnp.ndarray, sw: jnp.ndarray,
 
     The weight arrives already quantized (payload ``wq``, per-column
     scales ``sw``); only the activation is quantized here (per-row
-    dynamic scale — it changes every call, the weight does not).
+    dynamic scale — it changes every call, the weight does not).  The
+    Pallas lowering runs per shard of the mesh: rows over the data
+    axes, output columns over the model axis (a Mosaic call cannot be
+    partitioned by GSPMD).
     """
     from ..kernels.ops import qmatmul  # local: kernels import nn-free core
 
     sx = calibrate_scale(x2, qt, channel_axes=(0,))          # (T, 1)
     xq = jnp.clip(jnp.round(x2 / sx), qt.int_min, qt.int_max).astype(qt.dtype)
-    return qmatmul(xq, wq, sx, sw, bias=bias, act_spec=act_spec,
-                   act_gated=act_gated, out_dtype=ctx.compute_dtype,
-                   backend=ctx.backend)
+
+    def run(xq, wq, sx, sw, *bias):
+        return qmatmul(xq, wq, sx, sw, bias=bias[0] if bias else None,
+                       act_spec=act_spec, act_gated=act_gated,
+                       out_dtype=ctx.compute_dtype, backend=ctx.backend)
+
+    args = (xq, wq, sx, sw) + (() if bias is None else (bias,))
+    if resolve("qmatmul", ctx.backend) != "pallas":
+        return run(*args)
+    rows = "dp" if splits("dp", xq.shape[0]) else None
+    cols = "tp" if splits("tp", wq.shape[1]) else None
+    specs = (P(rows, None), P(None, cols), P(rows, None), P(None, cols),
+             P(cols))
+    return kernel_map(run, *args, in_specs=specs[:len(args)],
+                      out_specs=P(rows, cols))
 
 
 def _quantize_weight(w: jnp.ndarray, qt: FixedPointType):

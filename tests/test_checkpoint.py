@@ -76,7 +76,8 @@ class TestManager:
     def test_elastic_restore_with_shardings(self, tmp_path):
         """Restore onto an explicit sharding (single-device here; the
         512-device equivalence is exercised by the dry-run path)."""
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((1, 1), ("data", "model"))
         from repro.dist.sharding import named, param_specs
         st = make_state()
         m = CheckpointManager(str(tmp_path))

@@ -15,6 +15,7 @@ def run_with_devices(code: str, n: int = 8, timeout: int = 600):
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n}"
     env["PYTHONPATH"] = os.path.join(REPO, "src")
+    env["JAX_PLATFORMS"] = "cpu"      # a child never reaches for a chip
     r = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
                        capture_output=True, text=True, timeout=timeout,
                        env=env)
@@ -26,6 +27,7 @@ def run_with_devices(code: str, n: int = 8, timeout: int = 600):
 def test_sharded_train_step_runs_and_converges():
     out = run_with_devices("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.configs import get_config
         from repro.dist.sharding import param_specs, batch_specs, named
@@ -34,7 +36,7 @@ def test_sharded_train_step_runs_and_converges():
         from repro.train.step import build_train_step, init_state
         from repro.data.pipeline import make_batch
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         cfg = get_config("yi-6b").smoke()
         ctx = QuantContext(compute_dtype=jnp.float32)
         step = build_train_step(cfg, ctx, lr_fn=lambda s: 3e-3,
@@ -64,10 +66,11 @@ def test_sharded_train_step_runs_and_converges():
 def test_quantized_psum_matches_exact():
     out = run_with_devices("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.core.qtypes import FixedPointType
-        from repro.dist.compression import quantized_psum, shard_map
+        from repro.dist.compression import quantized_psum
 
-        mesh = jax.make_mesh((8,), ("pod",))
+        mesh = make_mesh((8,), ("pod",))
         x = jnp.asarray(np.random.RandomState(0).randn(8, 64),
                         jnp.float32)
 
@@ -76,7 +79,7 @@ def test_quantized_psum_matches_exact():
             q = quantized_psum(x, "pod", FixedPointType(8, 1))
             return exact, q
 
-        exact, q = shard_map(
+        exact, q = jax.shard_map(
             f, mesh=mesh, in_specs=jax.sharding.PartitionSpec("pod"),
             out_specs=jax.sharding.PartitionSpec("pod"))(x)
         rel = float(jnp.abs(exact - q).max() /
@@ -92,6 +95,7 @@ def test_elastic_checkpoint_across_meshes():
     """Save sharded on a (4,2) mesh, restore onto (2,4) and (8,1)."""
     out = run_with_devices("""
         import jax, jax.numpy as jnp, numpy as np, tempfile
+        from repro.launch.mesh import make_mesh
         from repro.checkpoint import CheckpointManager
         from repro.configs import get_config
         from repro.dist.sharding import param_specs, named
@@ -101,13 +105,13 @@ def test_elastic_checkpoint_across_meshes():
         fam = get_family(cfg)
         params = fam.init(jax.random.PRNGKey(0), cfg)
         d = tempfile.mkdtemp()
-        m1 = jax.make_mesh((4, 2), ("data", "model"))
+        m1 = make_mesh((4, 2), ("data", "model"))
         p1 = jax.device_put(params, named(param_specs(params, m1), m1))
         mgr = CheckpointManager(d)
         mgr.save({"params": p1}, 1, blocking=True)
 
         for shape in [(2, 4), (8, 1)]:
-            m2 = jax.make_mesh(shape, ("data", "model"))
+            m2 = make_mesh(shape, ("data", "model"))
             sh2 = named(param_specs({"params": params}, m2), m2)
             restored, step = mgr.restore_latest({"params": params},
                                                 shardings=sh2)
@@ -126,11 +130,12 @@ def test_pod_sharded_grad_compression_lowers():
     on a (2,2,2) pod mesh."""
     out = run_with_devices("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from jax.sharding import PartitionSpec as P
         from repro.core.qtypes import FixedPointType
         from repro.dist.compression import make_pod_sharded_grad_fn
 
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 
         def grad_fn(params, batch):
             def loss(p):

@@ -59,7 +59,7 @@ class TestPaddedTails:
         q, k, v = _qkv(2, 2, 2, sq, skv, 8)
         _check(q, k, v, causal=causal, bq=bq, bk=bk)
 
-    @settings(max_examples=10)
+    @settings(max_examples=10, deadline=None)
     @given(st.integers(1, 20), st.integers(0, 12),
            st.sampled_from([2, 4, 8]), st.sampled_from([2, 4, 8]),
            st.integers(0, 2 ** 16))
@@ -121,7 +121,7 @@ class TestGQAGroups:
             np.testing.assert_allclose(out[0, h], h // group + 1.0,
                                        rtol=1e-6)
 
-    @settings(max_examples=8)
+    @settings(max_examples=8, deadline=None)
     @given(st.sampled_from([(2, 1), (4, 1), (4, 2), (6, 2)]),
            st.integers(2, 12), st.integers(0, 2 ** 16))
     def test_sweep_gqa_vs_ref(self, heads, sq, seed):

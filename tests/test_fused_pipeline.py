@@ -121,7 +121,8 @@ class TestPrequantLinear:
         payload's FSDP axis — payload and scale get separate specs."""
         from jax.sharding import PartitionSpec as P
         from repro.dist.sharding import named, param_specs
-        mesh = jax.make_mesh((len(jax.devices()), 1), ("data", "model"))
+        from repro.launch.mesh import make_local_mesh
+        mesh = make_local_mesh()
         qp = ptq_params({"blk": {"w": jnp.ones((128, 256))}}, QT8)
         specs = param_specs(qp, mesh)
         assert isinstance(specs["blk"]["w"], QTensor)

@@ -6,7 +6,7 @@ import numpy as np
 import pytest
 
 from repro.launch.hlo_analysis import analyze_hlo
-from repro.launch.roofline import HW, RooflineReport, roofline
+from repro.launch.roofline import RooflineReport, peaks, roofline
 
 
 def _compile(fn, *args):
@@ -95,7 +95,7 @@ class TestRooflineReport:
             flops_per_chip=197e12, bytes_per_chip=819e9,
             wire_bytes_per_chip=0.0, bytes_all_per_chip=1e12,
             compute_s=1.0, memory_s=1.0, collective_s=0.1,
-            model_flops=197e12 * 256 * 0.5)
+            model_flops=197e12 * 256 * 0.5, peak_flops=197e12)
         assert rep.bottleneck in ("compute", "memory")
         assert rep.step_time == 1.0
         assert rep.mfu == pytest.approx(0.5)
@@ -104,6 +104,13 @@ class TestRooflineReport:
         rep = roofline(arch="t", shape="s", mesh="single", chips=256,
                        cost={"flops": 1.0},
                        hlo_text=TestCollectiveParsing.HLO,
-                       model_flops=1e12)
+                       model_flops=1e12, hw=peaks("TPU v5 lite"))
         assert rep.collective_s > 0
         assert rep.raw_cost_analysis["flops"] == 1.0
+
+    def test_peaks_keyed_by_device_kind(self):
+        hw = peaks("TPU v5 lite")
+        assert (hw.peak_flops, hw.hbm_bw) == (197e12, 819e9)
+        assert hw.source
+        with pytest.raises(KeyError):
+            peaks("cpu")            # no default row for an unknown chip

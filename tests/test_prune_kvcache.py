@@ -118,14 +118,15 @@ class TestErrorFeedback:
         env = dict(os.environ)
         env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
         env["PYTHONPATH"] = "src"
+        env["JAX_PLATFORMS"] = "cpu"  # a child never reaches for a chip
         code = textwrap.dedent("""
             import jax, jax.numpy as jnp, numpy as np
             from jax.sharding import PartitionSpec as P
             from repro.core.qtypes import FixedPointType
             from repro.dist.compression import (quantized_psum,
-                                                quantized_psum_ef,
-                                                shard_map)
-            mesh = jax.make_mesh((4,), ("pod",))
+                                                quantized_psum_ef)
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((4,), ("pod",))
             x = jnp.asarray(np.random.RandomState(0).randn(4, 64),
                             jnp.float32)
             qt = FixedPointType(4, 1)   # brutal 4-bit to expose bias
@@ -141,7 +142,7 @@ class TestErrorFeedback:
                     acc_q += quantized_psum(x, "pod", qt)
                 return exact, acc_ef / 24, acc_q / 24
 
-            exact, mean_ef, mean_q = shard_map(
+            exact, mean_ef, mean_q = jax.shard_map(
                 f, mesh=mesh, in_specs=P("pod"), out_specs=P("pod"))(x)
             err_ef = float(jnp.abs(mean_ef - exact).max())
             err_q = float(jnp.abs(mean_q - exact).max())

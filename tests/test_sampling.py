@@ -106,7 +106,7 @@ class TestJitBoundaryDeterminism:
 
 # ===========================================================================
 class TestSamplingSemantics:
-    @settings(max_examples=15)
+    @settings(max_examples=15, deadline=None)
     @given(st.integers(1, 5), st.integers(2, 128), st.integers(0, 2 ** 16),
            st.floats(0.05, 3.0), st.integers(0, 12))
     def test_sweep_fused_matches_ref_and_in_range(self, b, v, seed, temp, k):
@@ -120,7 +120,7 @@ class TestSamplingSemantics:
         np.testing.assert_array_equal(got, want)
         assert ((0 <= got) & (got < v)).all()
 
-    @settings(max_examples=10)
+    @settings(max_examples=10, deadline=None)
     @given(st.integers(1, 8), st.integers(0, 2 ** 16))
     def test_samples_stay_inside_top_k(self, k, seed):
         rs = np.random.RandomState(seed)

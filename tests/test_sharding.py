@@ -8,19 +8,20 @@ from jax.sharding import PartitionSpec as P
 
 from repro.dist.sharding import (batch_specs, cache_specs, guard_spec,
                                  param_specs)
+from repro.launch.mesh import make_mesh
 
 
 @pytest.fixture(scope="module")
 def mesh():
     n = len(jax.devices())
-    return jax.make_mesh((n, 1), ("data", "model"))
+    return make_mesh((n, 1), ("data", "model"))
 
 
 class TestGuardSpec:
     @settings(max_examples=60, deadline=None)
     @given(st.lists(st.integers(1, 64), min_size=1, max_size=4))
     def test_guard_never_violates_divisibility(self, dims, ):
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         spec = guard_spec(P(*(["data", "model", None, "data"][:len(dims)])),
                           dims, mesh)
         for axis, d in zip(spec, dims):
@@ -30,7 +31,7 @@ class TestGuardSpec:
                 assert d % size == 0
 
     def test_drops_nondivisible(self):
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         # with axis size 1 everything divides; simulate via tuple axis
         s = guard_spec(P(("data", "model")), (7,), mesh)
         assert s == P(None) or s == P(("data", "model"))  # 7 % 1 == 0
