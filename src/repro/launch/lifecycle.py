@@ -282,8 +282,14 @@ def validate_request(prompt, *, vocab: int, temperature=None, top_k=None,
 
 
 def request_row(*, ttft_s: float, gen_tokens: int, decode_s: float,
-                status: RequestStatus, priority=None) -> dict:
+                status: RequestStatus, priority=None,
+                queue_s: float = 0.0, req_id=None) -> dict:
     """One ``Engine.request_log`` row for a retired request.
+
+    ``queue_s`` is the time the request waited in the admission queue,
+    from submit to the admission call that took it (0 for a direct
+    slot-addressed add); ``ttft_s`` counts from submit too.  ``req_id``
+    is the request's id, the key of its ``Engine.results`` entry.
 
     ``tok_per_s`` is ``None`` — not ``0.0`` — when the decode interval
     is not measurable (``decode_s == 0`` under fake clocks, or a request
@@ -295,7 +301,9 @@ def request_row(*, ttft_s: float, gen_tokens: int, decode_s: float,
     ``"standard"`` / ``"batch"``) so rows stay JSON-serializable like
     ``status``; per-class percentile aggregation keys on it.
     """
-    return {"ttft_s": float(ttft_s), "gen_tokens": int(gen_tokens),
+    return {"id": req_id, "ttft_s": float(ttft_s),
+            "queue_s": float(queue_s),
+            "gen_tokens": int(gen_tokens),
             "decode_s": float(decode_s), "status": status.value,
             "priority": coerce_priority(priority).name.lower(),
             "tok_per_s": (gen_tokens / decode_s) if decode_s > 0

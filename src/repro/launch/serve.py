@@ -93,6 +93,7 @@ from typing import Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..configs import get_config
 from ..data.pipeline import SyntheticLM
@@ -115,6 +116,12 @@ from .mesh import make_local_mesh
 from .paging import PageAllocator
 from .prefix import PREFIX_OWNER, ROOT, PrefixIndex
 from .train import build_ctx
+
+#: the counters whose change over one admission sweep a traced
+#: ``engine.admit`` span carries as its metadata, so a reader of the
+#: trace can sum them over any window of it
+ADMIT_SPAN_COUNTERS = ("admitted", "queue_wait_s", "prefill_rows",
+                       "prefill_tokens")
 
 
 def _snap(a: np.ndarray) -> jnp.ndarray:
@@ -427,8 +434,18 @@ class Engine:
         self.waiting: deque = deque()
         #: aggregate serving counters (peak concurrency, admissions,
         #: generated tokens, decode walltime, speculation acceptance);
-        #: per-request rows land in ``request_log`` — see :meth:`stats`
+        #: per-request rows land in ``request_log`` — see :meth:`stats`.
+        #: Admission and transfer counts: ``queue_wait_s`` sums (admission
+        #: call - submit) over admitted requests; ``prefill_calls`` counts
+        #: runs of the prefill program, ``prefill_rows`` the lane x token
+        #: rows they computed, ``prefill_tokens`` the prompt tokens they
+        #: ingested (suffixes, under prefix hits); ``host_fetch_bytes``
+        #: counts the prefill logits and decode-block outputs copied to
+        #: the host (see :meth:`_fetch`).
         self.counters = {"peak_live": 0, "admitted": 0, "gen_tokens": 0,
+                         "queue_wait_s": 0.0, "prefill_calls": 0,
+                         "prefill_rows": 0, "prefill_tokens": 0,
+                         "host_fetch_bytes": 0,
                          "decode_s": 0.0, "verify_steps": 0,
                          "draft_accepted": 0, "preemptions": 0,
                          "cancellations": 0, "timeouts": 0, "failures": 0,
@@ -621,190 +638,194 @@ class Engine:
                       gen_len=None, temperature=None, top_k=None,
                       deadline_s=None, priority=None, _t_submit=None,
                       _ids=None, _deadlines=None, _prefix=None):
-        t_call = self.clock()
-        reqs = {int(s): validate_request(p, vocab=self.cfg.vocab,
-                                         temperature=temperature,
-                                         top_k=top_k, priority=priority)
-                for s, p in requests.items()}
-        if deadline_s is not None:
-            # validated as the dict-or-scalar it is: every entry checked
-            # on its own (collapsing to min() crashed on mixed None
-            # entries and pinned the whole batch to the tightest TTL in
-            # the validation error path)
-            validate_request([], vocab=self.cfg.vocab,
-                             deadline_s=deadline_s)
-        for s, p in reqs.items():
-            if p.shape[0] > self.max_len:
-                raise ValueError(
-                    f"prompt of {p.shape[0]} tokens does not fit the cache "
-                    f"(max_len={self.max_len}); refusing to clamp-write "
-                    f"the tail")
-            if p.size == 0:
-                reqs[s] = np.zeros((1,), np.int32)
-        if not reqs:
-            return
+        with TraceAnnotation("engine.admit.pages"):
+            t_call = self.clock()
+            reqs = {int(s): validate_request(p, vocab=self.cfg.vocab,
+                                             temperature=temperature,
+                                             top_k=top_k, priority=priority)
+                    for s, p in requests.items()}
+            if deadline_s is not None:
+                # validated as the dict-or-scalar it is: every entry checked
+                # on its own (collapsing to min() crashed on mixed None
+                # entries and pinned the whole batch to the tightest TTL in
+                # the validation error path)
+                validate_request([], vocab=self.cfg.vocab,
+                                 deadline_s=deadline_s)
+            for s, p in reqs.items():
+                if p.shape[0] > self.max_len:
+                    raise ValueError(
+                        f"prompt of {p.shape[0]} tokens does not fit the "
+                        f"cache (max_len={self.max_len}); refusing to "
+                        f"clamp-write the tail")
+                if p.size == 0:
+                    reqs[s] = np.zeros((1,), np.int32)
+            if not reqs:
+                return
 
-        def per_slot(v, s, default):
-            if v is None:
-                return default
-            return v.get(s, default) if isinstance(v, dict) else v
+            def per_slot(v, s, default):
+                if v is None:
+                    return default
+                return v.get(s, default) if isinstance(v, dict) else v
 
-        def stop_of(s, plen):
-            return self._token_budget(plen, per_slot(gen_len, s, None))
+            def stop_of(s, plen):
+                return self._token_budget(plen, per_slot(gen_len, s, None))
 
-        prefix_of: Dict[int, dict] = {}
-        if self.paged:
-            # one page allocation covers the request's whole budget, so
-            # the block table is static for its lifetime (the fused
-            # decode loop never needs a mid-block allocator callback).
-            # Feasibility is checked for the whole group BEFORE touching
-            # any allocator state, so a failed admission leaves the
-            # engine exactly as it was.
-            held: Dict[int, List[int]] = {}
-            if self.prefix_cache:
-                # match each prompt's longest committed prefix and take
-                # a reference on the hit pages IMMEDIATELY (before any
-                # eviction/preemption below can run): a held page has
-                # refcount >= 2 and is untouchable by the eviction
-                # sweep.  try_admit matched+shared at pop time and
-                # passes its holds through ``_prefix``; either way this
-                # call owns them and must release them on failure.
-                for s, p in reqs.items():
-                    info = (_prefix or {}).get(s)
-                    h = None
-                    if info is None:
-                        info = self._match_prefix(p)
-                        h = info["shared"] + (
-                            [info["cow"]] if info["cow"] is not None
-                            else [])
-                        if h:
-                            self.allocator.share(h)
-                    prefix_of[s] = info
-                    held[s] = h if h is not None else (
-                        info["shared"] + ([info["cow"]]
-                                          if info["cow"] is not None
-                                          else []))
-            cls_of = {s: coerce_priority(per_slot(priority, s, None))
-                      for s in reqs}
-            floor = min(cls_of.values())
-            needs = {s: self.allocator.pages_for(stop_of(s, p.shape[0]))
-                     - len(prefix_of[s]["shared"] if s in prefix_of else ())
-                     for s, p in reqs.items()}
-            recyclable = sum(len(self._slot_pages.get(s, ())) for s in reqs)
+            prefix_of: Dict[int, dict] = {}
+            if self.paged:
+                # one page allocation covers the request's whole budget, so
+                # the block table is static for its lifetime (the fused
+                # decode loop never needs a mid-block allocator callback).
+                # Feasibility is checked for the whole group BEFORE touching
+                # any allocator state, so a failed admission leaves the
+                # engine exactly as it was.
+                held: Dict[int, List[int]] = {}
+                if self.prefix_cache:
+                    # match each prompt's longest committed prefix and take
+                    # a reference on the hit pages IMMEDIATELY (before any
+                    # eviction/preemption below can run): a held page has
+                    # refcount >= 2 and is untouchable by the eviction
+                    # sweep.  try_admit matched+shared at pop time and
+                    # passes its holds through ``_prefix``; either way this
+                    # call owns them and must release them on failure.
+                    for s, p in reqs.items():
+                        info = (_prefix or {}).get(s)
+                        h = None
+                        if info is None:
+                            info = self._match_prefix(p)
+                            h = info["shared"] + (
+                                [info["cow"]] if info["cow"] is not None
+                                else [])
+                            if h:
+                                self.allocator.share(h)
+                        prefix_of[s] = info
+                        held[s] = h if h is not None else (
+                            info["shared"] + ([info["cow"]]
+                                              if info["cow"] is not None
+                                              else []))
+                cls_of = {s: coerce_priority(per_slot(priority, s, None))
+                          for s in reqs}
+                floor = min(cls_of.values())
+                needs = {s: self.allocator.pages_for(stop_of(s, p.shape[0]))
+                         - len(prefix_of[s]["shared"] if s in prefix_of
+                               else ())
+                         for s, p in reqs.items()}
+                recyclable = sum(len(self._slot_pages.get(s, ()))
+                                 for s in reqs)
 
-            def short():
-                return (sum(needs.values())
-                        - self.allocator.free_pages - recyclable)
+                def short():
+                    return (sum(needs.values())
+                            - self.allocator.free_pages - recyclable)
 
-            if short() > 0 and self.prefix_cache:
-                # cold index entries yield before any running request
-                # does — dropping unreferenced cached prefixes is free
-                # (class floor: a cached chunk more important than every
-                # request being admitted stays)
-                self.prefix_index.evict(
-                    self.allocator, short(),
-                    floor=floor if self.allocator.class_quotas else None)
-            if short() > 0 and self.preempt:
-                # graceful degradation instead of MemoryError: spill
-                # running victims until the admission fits — but only
-                # victims at or below the most important class being
-                # admitted (a BATCH add must never spill REALTIME work)
-                self._preempt_until(sum(needs.values()) - recyclable,
-                                    exclude=set(reqs), floor=floor)
-            if short() > 0:
-                for h in held.values():
-                    if h:
-                        self.allocator.free(h)      # release the match
-                raise MemoryError(
-                    f"page pool exhausted: admission needs "
-                    f"{sum(needs.values())} pages, free "
-                    f"{self.allocator.free_pages} of "
-                    f"{self.allocator.num_pages} (queue through submit() "
-                    f"to wait for pages)")
-            if self.allocator.class_quotas:
-                # group quota preflight BEFORE any state moves (same
-                # atomicity rule as the pool check above): count the
-                # pages the recycle loop below will release as credit
-                needs_cls: Dict[PriorityClass, int] = {}
-                for s in reqs:
-                    needs_cls[cls_of[s]] = (needs_cls.get(cls_of[s], 0)
-                                            + needs[s])
-                release = [p for s in reqs for p in
-                           (self._slot_shared.get(s, [])
-                            if self.prefix_cache else [])
-                           + self._slot_pages.get(s, [])]
-                freed, uncharge = self.allocator.release_credit(release)
-                qmsg = self.allocator.quota_violation(
-                    needs_cls, freed=freed, uncharge=uncharge)
-                if qmsg is not None:
+                if short() > 0 and self.prefix_cache:
+                    # cold index entries yield before any running request
+                    # does — dropping unreferenced cached prefixes is free
+                    # (class floor: a cached chunk more important than every
+                    # request being admitted stays)
+                    self.prefix_index.evict(
+                        self.allocator, short(),
+                        floor=floor if self.allocator.class_quotas else None)
+                if short() > 0 and self.preempt:
+                    # graceful degradation instead of MemoryError: spill
+                    # running victims until the admission fits — but only
+                    # victims at or below the most important class being
+                    # admitted (a BATCH add must never spill REALTIME work)
+                    self._preempt_until(sum(needs.values()) - recyclable,
+                                        exclude=set(reqs), floor=floor)
+                if short() > 0:
                     for h in held.values():
                         if h:
-                            self.allocator.free(h)
+                            self.allocator.free(h)      # release the match
                     raise MemoryError(
-                        f"class quota exceeded: {qmsg} (queue through "
-                        f"submit() to wait)")
-            for s in reqs:
-                # direct slot-addressed admission over a slot that still
-                # holds pages (no finish() in between) recycles them
-                if self.prefix_cache:
-                    self.allocator.free(self._slot_shared.pop(s, []))
-                    self._pub.pop(s, None)
-                if s in self._slot_pages:
-                    self.allocator.free(self._slot_pages.pop(s))
-            for s in reqs:
-                info = prefix_of.get(s)
-                shared = info["shared"] if info else []
-                pages = self.allocator.alloc(needs[s], owner=s,
-                                             cls=cls_of[s])
-                self._slot_pages[s] = pages
-                self.block_tables[s, :] = self._trash
-                self.block_tables[s, :len(shared)] = shared
-                self.block_tables[s, len(shared):len(shared)
-                                  + len(pages)] = pages
-                if self.prefix_cache:
-                    self._slot_shared[s] = list(shared)
-                    self._pub[s] = ((info["depth"], info["key"])
-                                    if info else (0, ROOT))
-                if info and info["cow"] is not None:
-                    # full-prompt hit: the boundary page still receives
-                    # this slot's writes (last prompt row + decode), so
-                    # it is copy-on-write duplicated into the slot's
-                    # first private page before anything runs
-                    self.cache = self._copy_page(
-                        self.cache, jnp.int32(info["cow"]),
-                        jnp.int32(pages[0]))
-                    self.allocator.free([info["cow"]])
-                    self.counters["cow_copies"] += 1
-                if info and (info["shared"] or info["cow"] is not None):
-                    self.counters["prefix_hits"] += 1
-                    self.counters["prefix_hit_pages"] += (
-                        len(shared)
-                        + (1 if info["cow"] is not None else 0))
-                    self.counters["prefix_tokens_saved"] += info["start"]
-            self._flush_block_tables()
+                        f"page pool exhausted: admission needs "
+                        f"{sum(needs.values())} pages, free "
+                        f"{self.allocator.free_pages} of "
+                        f"{self.allocator.num_pages} (queue through submit() "
+                        f"to wait for pages)")
+                if self.allocator.class_quotas:
+                    # group quota preflight BEFORE any state moves (same
+                    # atomicity rule as the pool check above): count the
+                    # pages the recycle loop below will release as credit
+                    needs_cls: Dict[PriorityClass, int] = {}
+                    for s in reqs:
+                        needs_cls[cls_of[s]] = (needs_cls.get(cls_of[s], 0)
+                                                + needs[s])
+                    release = [p for s in reqs for p in
+                               (self._slot_shared.get(s, [])
+                                if self.prefix_cache else [])
+                               + self._slot_pages.get(s, [])]
+                    freed, uncharge = self.allocator.release_credit(release)
+                    qmsg = self.allocator.quota_violation(
+                        needs_cls, freed=freed, uncharge=uncharge)
+                    if qmsg is not None:
+                        for h in held.values():
+                            if h:
+                                self.allocator.free(h)
+                        raise MemoryError(
+                            f"class quota exceeded: {qmsg} (queue through "
+                            f"submit() to wait)")
+                for s in reqs:
+                    # direct slot-addressed admission over a slot that still
+                    # holds pages (no finish() in between) recycles them
+                    if self.prefix_cache:
+                        self.allocator.free(self._slot_shared.pop(s, []))
+                        self._pub.pop(s, None)
+                    if s in self._slot_pages:
+                        self.allocator.free(self._slot_pages.pop(s))
+                for s in reqs:
+                    info = prefix_of.get(s)
+                    shared = info["shared"] if info else []
+                    pages = self.allocator.alloc(needs[s], owner=s,
+                                                 cls=cls_of[s])
+                    self._slot_pages[s] = pages
+                    self.block_tables[s, :] = self._trash
+                    self.block_tables[s, :len(shared)] = shared
+                    self.block_tables[s, len(shared):len(shared)
+                                      + len(pages)] = pages
+                    if self.prefix_cache:
+                        self._slot_shared[s] = list(shared)
+                        self._pub[s] = ((info["depth"], info["key"])
+                                        if info else (0, ROOT))
+                    if info and info["cow"] is not None:
+                        # full-prompt hit: the boundary page still receives
+                        # this slot's writes (last prompt row + decode), so
+                        # it is copy-on-write duplicated into the slot's
+                        # first private page before anything runs
+                        self.cache = self._copy_page(
+                            self.cache, jnp.int32(info["cow"]),
+                            jnp.int32(pages[0]))
+                        self.allocator.free([info["cow"]])
+                        self.counters["cow_copies"] += 1
+                    if info and (info["shared"] or info["cow"] is not None):
+                        self.counters["prefix_hits"] += 1
+                        self.counters["prefix_hit_pages"] += (
+                            len(shared)
+                            + (1 if info["cow"] is not None else 0))
+                        self.counters["prefix_tokens_saved"] += info["start"]
+                self._flush_block_tables()
 
-        # a recycled slot may have idled for whole blocks since
-        # finish(): decode advances dead lanes too (the held pad token
-        # drives recurrent state forward), so zero each such lane NOW —
-        # prefill must start from clean state, not from whatever
-        # accumulated while the slot sat empty.  (Chunked-prefill
-        # garbage writes into a clean lane don't dirty it: the
-        # visibility mask + decode's write-before-attend keep those
-        # rows unobservable, the same invariant as the cache margin.)
-        for s in reqs:
-            if not self._clean[s]:
-                self.cache = self._invalidate(self.cache, jnp.int32(s))
-                if self.draft is not None:
-                    # the draft scan advances dead lanes too, so the
-                    # drafter's recurrent/KV lane is just as dirty
-                    self.draft_cache = self._draft_invalidate(
-                        self.draft_cache, jnp.int32(s))
-        starts = {s: info["start"] for s, info in prefix_of.items()
-                  if info["start"]}
-        if self.chunked:
-            first = self._prefill_chunked(reqs, starts)
-        else:
-            first = self._prefill_looped(reqs)
+            # a recycled slot may have idled for whole blocks since
+            # finish(): decode advances dead lanes too (the held pad token
+            # drives recurrent state forward), so zero each such lane NOW —
+            # prefill must start from clean state, not from whatever
+            # accumulated while the slot sat empty.  (Chunked-prefill
+            # garbage writes into a clean lane don't dirty it: the
+            # visibility mask + decode's write-before-attend keep those
+            # rows unobservable, the same invariant as the cache margin.)
+            for s in reqs:
+                if not self._clean[s]:
+                    self.cache = self._invalidate(self.cache, jnp.int32(s))
+                    if self.draft is not None:
+                        # the draft scan advances dead lanes too, so the
+                        # drafter's recurrent/KV lane is just as dirty
+                        self.draft_cache = self._draft_invalidate(
+                            self.draft_cache, jnp.int32(s))
+            starts = {s: info["start"] for s, info in prefix_of.items()
+                      if info["start"]}
+        with TraceAnnotation("engine.prefill"):
+            if self.chunked:
+                first = self._prefill_chunked(reqs, starts)
+            else:
+                first = self._prefill_looped(reqs)
         if self.spec and self.draft is not None:
             self._prefill_draft(reqs)
         t_first = self.clock()
@@ -822,7 +843,10 @@ class Engine:
             # else from this call's start (direct slot-addressed adds)
             self.hist[s, :] = 0
             self.hist[s, :p.shape[0]] = p
+            # a queued request waited from submit() to this call; a
+            # direct add did not wait
             t_sub = (_t_submit or {}).get(s, t_call)
+            self.counters["queue_wait_s"] += t_call - t_sub
             rid = (_ids or {}).get(s)
             if rid is None:
                 rid = self._mint_id()
@@ -833,6 +857,7 @@ class Engine:
                 dl = None if d is None else t_call + float(d)
             cls = coerce_priority(per_slot(priority, s, None))
             self._req_meta[s] = {"id": rid, "ttft_s": t_first - t_sub,
+                                 "queue_s": t_call - t_sub,
                                  "t_admit": t_first, "deadline": dl,
                                  "priority": cls}
             self._class_count(cls, "admitted")
@@ -1128,7 +1153,8 @@ class Engine:
     def retire_finished(self) -> int:
         """finish() every slot whose generation ended (frees its lane —
         and, paged, its pages) so try_admit can reuse both."""
-        with self._journal_scope("retire"):
+        with TraceAnnotation("engine.retire"), \
+                self._journal_scope("retire"):
             n = 0
             for s in range(self.batch):
                 if self.outputs[s] is not None and not self.live[s]:
@@ -1177,8 +1203,15 @@ class Engine:
         spilled until the head fits — head-of-line blocking becomes
         time slicing.  A head whose class has a TTFT SLO target and is
         already past it escalates immediately."""
-        with self._journal_scope("admit"):
-            return self._try_admit()
+        with TraceAnnotation("engine.admit") as span, \
+                self._journal_scope("admit"):
+            if not TraceAnnotation.is_enabled():
+                return self._try_admit()
+            before = [self.counters[k] for k in ADMIT_SPAN_COUNTERS]
+            n = self._try_admit()
+            span.set_metadata(**{k: self.counters[k] - b for k, b in
+                                 zip(ADMIT_SPAN_COUNTERS, before)})
+            return n
 
     def _try_admit(self) -> int:
         free = [s for s in range(self.batch)
@@ -1534,20 +1567,27 @@ class Engine:
                 break
             # live slots keep their own position: their (ignored) writes
             # land at [pos, pos+chunk) inside the margin, never clamped.
-            cur = self.pos.copy()
-            if park is None:
-                cur[fresh] = c0
-            else:
-                for s in reqs:
-                    cur[s] = min(offs[s] + c0, park)
-            logits, self.cache = self.prefill(
-                self.params, {"tokens": _snap(toks[:, c0:c0 + chunk])},
-                self.cache, _snap(cur))
-            logits = np.asarray(logits)
-            for s, t in sufs.items():
-                t_last = t.shape[0] - 1
-                if c0 <= t_last < c0 + chunk:
-                    first[s] = int(np.argmax(logits[s, t_last - c0]))
+            with TraceAnnotation("engine.prefill.dispatch"):
+                cur = self.pos.copy()
+                if park is None:
+                    cur[fresh] = c0
+                else:
+                    for s in reqs:
+                        cur[s] = min(offs[s] + c0, park)
+                logits, self.cache = self.prefill(
+                    self.params, {"tokens": _snap(toks[:, c0:c0 + chunk])},
+                    self.cache, _snap(cur))
+            self.counters["prefill_calls"] += 1
+            self.counters["prefill_rows"] += self.batch * chunk
+            with TraceAnnotation("engine.prefill.fetch"):
+                logits = self._fetch(logits)
+            with TraceAnnotation("engine.prefill.argmax"):
+                for s, t in sufs.items():
+                    t_last = t.shape[0] - 1
+                    if c0 <= t_last < c0 + chunk:
+                        first[s] = int(np.argmax(logits[s, t_last - c0]))
+        self.counters["prefill_tokens"] += sum(t.shape[0]
+                                               for t in sufs.values())
         return first
 
     def _prefill_looped(self, reqs) -> Dict[int, int]:
@@ -1570,7 +1610,7 @@ class Engine:
                 logits, self.cache = self.decode(
                     self.params, self.cache, _snap(tok), _snap(self.pos))
                 self.pos[s] += 1
-            first[s] = int(jnp.argmax(logits[s, -1]))
+            first[s] = int(self._fetch(jnp.argmax(logits[s, -1])))
             self.cache = self._merge(self.cache, before, jnp.int32(s))
             # keep pos at prompt length: later slots' loops must not write
             # into this slot's freshly-filled rows (add_requests re-asserts
@@ -1655,17 +1695,18 @@ class Engine:
         every ``snapshot_every`` blocks a full snapshot (with the
         journal cursor) bounds the replay tail.
         """
-        if self._journal is not None and self._jmute == 0:
-            self._blocks_since_snap += 1
-            if (self.snapshot_every
-                    and self._blocks_since_snap > self.snapshot_every):
-                # snapshot BEFORE this block's journal record: the
-                # cursor must not cover a block the snapshot state
-                # hasn't executed, or recovery would skip it
-                self._save_durable()
-                self._blocks_since_snap = 1
-        with self._journal_scope("block", int(n), ahead=True):
-            return self._step_many(n)
+        with TraceAnnotation("engine.step"):
+            if self._journal is not None and self._jmute == 0:
+                self._blocks_since_snap += 1
+                if (self.snapshot_every
+                        and self._blocks_since_snap > self.snapshot_every):
+                    # snapshot BEFORE this block's journal record: the
+                    # cursor must not cover a block the snapshot state
+                    # hasn't executed, or recovery would skip it
+                    self._save_durable()
+                    self._blocks_since_snap = 1
+            with self._journal_scope("block", int(n), ahead=True):
+                return self._step_many(n)
 
     def _step_many(self, n: int):
         self._round += 1
@@ -1862,24 +1903,28 @@ class Engine:
             loop = jax.jit(build_decode_loop(self.cfg, self.ctx, n),
                            donate_argnums=(1,))
             self._loops[n] = loop
-        sample_params = {"temperature": _snap(self.temperature),
-                         "top_k": _snap(self.top_k)}
-        # all-greedy batches skip the top-k sorts / noise generation
-        # (greedy consumes no PRNG state, so the stream is unaffected)
-        key = self._key if (self.temperature > 0).any() else None
-        self.cache, tokens, pos, live, block, block_live, fault = loop(
-            self.params, self.cache, _snap(self.tokens), _snap(self.pos),
-            _snap(self.live), _snap(self.stop_pos), sample_params,
-            key, jnp.int32(self._gen_step), jnp.int32(self.eos_id))
+        with TraceAnnotation("engine.decode.dispatch"):
+            sample_params = {"temperature": _snap(self.temperature),
+                             "top_k": _snap(self.top_k)}
+            # all-greedy batches skip the top-k sorts / noise generation
+            # (greedy consumes no PRNG state, so the stream is unaffected)
+            key = self._key if (self.temperature > 0).any() else None
+            self.cache, tokens, pos, live, block, block_live, fault = loop(
+                self.params, self.cache, _snap(self.tokens),
+                _snap(self.pos), _snap(self.live), _snap(self.stop_pos),
+                sample_params, key, jnp.int32(self._gen_step),
+                jnp.int32(self.eos_id))
         # ONE host sync for the whole block (np.asarray blocks until the
         # device values are ready; .copy() detaches the engine's mutable
         # state from the device buffers)
-        block = np.asarray(block)
-        block_live = np.asarray(block_live)
-        self.tokens = np.asarray(tokens).copy()
-        self.pos = np.asarray(pos).copy()
-        self.live = np.asarray(live).copy()
-        return block, block_live, np.asarray(fault)
+        with TraceAnnotation("engine.decode.fetch"):
+            block = self._fetch(block)
+            block_live = self._fetch(block_live)
+            self.tokens = self._fetch(tokens).copy()
+            self.pos = self._fetch(pos).copy()
+            self.live = self._fetch(live).copy()
+            fault = self._fetch(fault)
+        return block, block_live, fault
 
     def _block_spec(self, n: int):
         """One fused speculative block (n draft→verify rounds).
@@ -1909,29 +1954,32 @@ class Engine:
                                        ngram=self.spec_ngram, **kw),
                 donate_argnums=(1, 11) if model_draft else (1,))
             self._spec_loops[(n, self.spec_k)] = loop
-        sample_params = {"temperature": _snap(self.temperature),
-                         "top_k": _snap(self.top_k)}
-        key = self._key if (self.temperature > 0).any() else None
-        common = (self.params, self.cache, _snap(self.tokens),
-                  _snap(self.pos), _snap(self.live), _snap(self.stop_pos),
-                  sample_params, key, jnp.int32(self._gen_step),
-                  jnp.int32(self.eos_id))
-        if model_draft:
-            out = loop(*common, self.draft[1], self.draft_cache)
-        else:
-            out = loop(*common, _snap(self.hist))
+        with TraceAnnotation("engine.decode.dispatch"):
+            sample_params = {"temperature": _snap(self.temperature),
+                             "top_k": _snap(self.top_k)}
+            key = self._key if (self.temperature > 0).any() else None
+            common = (self.params, self.cache, _snap(self.tokens),
+                      _snap(self.pos), _snap(self.live),
+                      _snap(self.stop_pos), sample_params, key,
+                      jnp.int32(self._gen_step), jnp.int32(self.eos_id))
+            if model_draft:
+                out = loop(*common, self.draft[1], self.draft_cache)
+            else:
+                out = loop(*common, _snap(self.hist))
         (self.cache, tokens, pos, live, aux, block, block_live,
          accepted, fault) = out
-        block = np.asarray(block)
-        block_live = np.asarray(block_live)
-        accepted = np.asarray(accepted)
-        self.tokens = np.asarray(tokens).copy()
-        self.pos = np.asarray(pos).copy()
-        self.live = np.asarray(live).copy()
-        if model_draft:
-            self.draft_cache = aux
-        else:
-            self.hist = np.asarray(aux).copy()
+        with TraceAnnotation("engine.decode.fetch"):
+            block = self._fetch(block)
+            block_live = self._fetch(block_live)
+            accepted = self._fetch(accepted)
+            self.tokens = self._fetch(tokens).copy()
+            self.pos = self._fetch(pos).copy()
+            self.live = self._fetch(live).copy()
+            fault = self._fetch(fault)
+            if model_draft:
+                self.draft_cache = aux
+            else:
+                self.hist = self._fetch(aux).copy()
         # acceptance telemetry: rounds in which a slot was live, and
         # how many drafts each such round committed (0..spec_k)
         step_live = block_live.reshape(n, self.spec_k + 1,
@@ -1944,7 +1992,14 @@ class Engine:
         # step_many only after the block survives the fault check, so
         # a restored-and-replayed block is observed exactly once
         self._last_spec_obs = (rounds, acc)
-        return block, block_live, np.asarray(fault)
+        return block, block_live, fault
+
+    def _fetch(self, x) -> np.ndarray:
+        """Copy a serving output to the host (waiting for the program
+        that makes it), counting its bytes in ``host_fetch_bytes``."""
+        a = np.asarray(x)
+        self.counters["host_fetch_bytes"] += a.nbytes
+        return a
 
     def step(self):
         """Per-token decode: the n=1 decode loop (baseline path)."""
@@ -1967,7 +2022,8 @@ class Engine:
             cls = coerce_priority(meta.get("priority"))
             done = meta.get("t_done", self.clock())
             self.request_log.append(request_row(
-                ttft_s=meta["ttft_s"],
+                ttft_s=meta["ttft_s"], queue_s=meta.get("queue_s", 0.0),
+                req_id=meta["id"],
                 gen_tokens=len(self.outputs[slot] or []),
                 decode_s=done - meta["t_admit"], status=status,
                 priority=cls))
@@ -2262,9 +2318,12 @@ class Engine:
         Combines the running counters with per-request rows from
         ``request_log``: time-to-first-token (submit→first token for
         queued requests), engine decode throughput (committed tokens
-        per second of block walltime, syncs included), and — under
-        speculation — the mean number of drafted tokens accepted per
-        verify round (committed tokens per round = that + 1).
+        per second of block walltime, syncs included), the mean queue
+        wait of admitted requests, the prefill program's calls and the
+        share of its rows that held prompt tokens, the bytes fetched to
+        the host, and — under speculation — the mean number of drafted
+        tokens accepted per verify round (committed tokens per round =
+        that + 1).
         """
         c = dict(self.counters)
         out = {"requests": len(self.done), "admitted": c["admitted"],
@@ -2275,7 +2334,17 @@ class Engine:
                # request_row applies per request, so aggregates skip the
                # value instead of reporting a fictitious stall
                "decode_tok_per_s": (c["gen_tokens"] / c["decode_s"]
-                                    if c["decode_s"] > 0 else None)}
+                                    if c["decode_s"] > 0 else None),
+               # admission: mean queue wait of the admitted requests, and
+               # the share (0-1) of the prefill program's lane x token
+               # rows that held prompt tokens (None before any prefill)
+               "queue_wait_mean_s": (c["queue_wait_s"] / c["admitted"]
+                                     if c["admitted"] else None),
+               "prefill_calls": c["prefill_calls"],
+               "prefill_useful_share": (
+                   c["prefill_tokens"] / c["prefill_rows"]
+                   if c["prefill_rows"] else None),
+               "host_fetch_bytes": c["host_fetch_bytes"]}
         if self.request_log:
             out["ttft_mean_s"] = float(np.mean(
                 [r["ttft_s"] for r in self.request_log]))

@@ -8,8 +8,9 @@ hybrid pages-KV-only, ssm no-KV) under f32 and pre-quantized int8
 weights, including requests admitted mid-stream onto freshly recycled
 pages and prompts whose pages are physically non-contiguous.
 
-Plus the allocator's own invariants (hypothesis-stub sweeps) and the
-``add_requests`` long-prompt rejection fix.
+Plus the allocator's own invariants (hypothesis-stub sweeps), the
+``add_requests`` long-prompt rejection fix, and the engine's admission
+and transfer counters.
 """
 
 import jax
@@ -413,3 +414,126 @@ class TestPageAllocatorProperties:
         with pytest.raises(ValueError, match="not a valid free page"):
             alloc.adopt([99], owner="b")
         assert alloc.state() == before               # atomic: no change
+
+
+# ===========================================================================
+class _Clock:
+    """A clock that moves only when told to."""
+
+    def __init__(self, t=0.0):
+        self.t = float(t)
+
+    def __call__(self):
+        return self.t
+
+
+class TestServingCounters:
+    """The engine's admission and transfer counters are exact at smoke
+    size, and its step programs keep the names the benchmark's trace
+    reduction matches."""
+
+    def _engine(self, **kw):
+        cfg, ctx, params, mesh = _setup("lm", "f32")
+        return Engine(cfg, ctx, params, mesh, batch=2, max_len=32,
+                      paged=True, page_size=8, prefill_chunk=8, **kw)
+
+    def test_prefill_and_fetch_counters(self):
+        """Two prompts of 12 and 16 tokens: two chunks of 8 over both
+        lanes, then one decode block of 2 steps."""
+        cfg, _, _, mesh = _setup("lm", "f32")
+        prompts = _prompts(cfg, (12, 16), seed=4)
+        with use_mesh(mesh):
+            eng = self._engine()
+            for p in prompts:
+                eng.submit(p, gen_len=4)
+            eng.try_admit()
+            c = dict(eng.counters)
+            eng.step_many(2)
+        assert c["prefill_calls"] == 2
+        assert c["prefill_rows"] == 2 * 2 * 8            # calls x B x chunk
+        assert c["prefill_tokens"] == 12 + 16
+        logits = 2 * (2 * 8 * cfg.vocab * 4)             # f32 per call
+        assert c["host_fetch_bytes"] == logits
+        # block (2, B) int32 and its live mask; tokens (B, 1), pos (B,)
+        # int32; live and fault (B,) bool
+        block = 2 * 2 * 4 + 2 * 2 + 2 * 4 + 2 * 4 + 2 + 2
+        assert eng.counters["host_fetch_bytes"] == logits + block
+        st = eng.stats()
+        assert st["prefill_calls"] == 2
+        assert st["prefill_useful_share"] == pytest.approx(28 / 32)
+        assert st["host_fetch_bytes"] == logits + block
+
+    def test_prefill_tokens_count_suffixes_under_prefix_hits(self):
+        """A prompt whose first two pages are cached ingests only its
+        suffix: 4 tokens in one chunk."""
+        cfg, _, _, mesh = _setup("lm", "f32")
+        first = _prompts(cfg, (16,), seed=5)[0]
+        second = np.concatenate([first, _prompts(cfg, (4,), seed=6)[0]])
+        with use_mesh(mesh):
+            eng = self._engine(prefix_cache=True)
+            eng.submit(first, gen_len=2)
+            eng.try_admit()
+            eng.step_many(2)
+            eng.retire_finished()
+            c = dict(eng.counters)
+            eng.submit(second, gen_len=2)
+            eng.try_admit()
+        assert eng.counters["prefix_hits"] == c["prefix_hits"] + 1
+        assert eng.counters["prefill_tokens"] - c["prefill_tokens"] == 4
+        assert eng.counters["prefill_calls"] - c["prefill_calls"] == 1
+        assert eng.counters["prefill_rows"] - c["prefill_rows"] == 2 * 8
+
+    def test_queue_wait_from_the_clock(self):
+        """Requests submitted at t=1 and t=2 and admitted at t=5 waited
+        4 s and 3 s; a direct add waited none."""
+        cfg, _, _, mesh = _setup("lm", "f32")
+        prompts = _prompts(cfg, (6, 6, 6), seed=7)
+        clock = _Clock(1.0)
+        with use_mesh(mesh):
+            eng = self._engine(clock=clock)
+            ids = [eng.submit(prompts[0], gen_len=2)]
+            clock.t = 2.0
+            ids.append(eng.submit(prompts[1], gen_len=2))
+            clock.t = 5.0
+            eng.try_admit()
+            assert eng.counters["queue_wait_s"] == pytest.approx(7.0)
+            eng.step_many(2)
+            eng.retire_finished()
+            eng.add_requests({0: prompts[2]}, gen_len=2)
+            eng.step_many(2)
+            eng.retire_finished()
+        assert eng.counters["queue_wait_s"] == pytest.approx(7.0)
+        rows = {r["id"]: r for r in eng.request_log}
+        assert rows[ids[0]]["queue_s"] == pytest.approx(4.0)
+        assert rows[ids[1]]["queue_s"] == pytest.approx(3.0)
+        assert len(rows) == 3 and set(ids) <= set(eng.results)
+        direct = [r for i, r in rows.items() if i not in ids]
+        assert direct[0]["queue_s"] == 0.0
+        assert eng.stats()["queue_wait_mean_s"] == pytest.approx(7.0 / 3)
+
+    @pytest.mark.parametrize("program", ["prefill_step", "decode_loop"])
+    def test_step_programs_keep_their_names(self, program):
+        """The benchmark finds the step programs in a device trace by
+        these names (``decode_step_ms.chat``, ``prefill_call_ms.chat``,
+        ``paged_attn_roofline``): a rename fails here."""
+        cfg, _, _, mesh = _setup("lm", "f32")
+        with use_mesh(mesh):
+            eng = self._engine()
+            eng.submit(_prompts(cfg, (6,), seed=8)[0], gen_len=4)
+            eng.try_admit()
+            eng.step_many(2)
+            b = eng.batch
+            if program == "prefill_step":
+                lowered = eng.prefill.lower(
+                    eng.params, {"tokens": jnp.zeros((b, 8), jnp.int32)},
+                    eng.cache, jnp.zeros((b,), jnp.int32))
+            else:
+                lowered = eng._loops[2].lower(  # noqa: SLF001
+                    eng.params, eng.cache, jnp.zeros((b, 1), jnp.int32),
+                    jnp.zeros((b,), jnp.int32), jnp.zeros((b,), bool),
+                    jnp.zeros((b,), jnp.int32),
+                    {"temperature": jnp.zeros((b,), jnp.float32),
+                     "top_k": jnp.zeros((b,), jnp.int32)},
+                    None, jnp.int32(0), jnp.int32(eng.eos_id))
+        head = lowered.compile().as_text().splitlines()[0]
+        assert head.startswith("HloModule ") and program in head.split()[1]
