@@ -17,9 +17,10 @@ realized at framework level, as a fused quantized dense pipeline:
   ``build_prefill_step``: all fresh slots advance together, one
   full-batch model call per ``prefill_chunk`` tokens, i.e.
   O(prompt_len / chunk) steps total instead of O(prompt_len) decode
-  steps *per slot*.  Slots mid-generation are untouched: their chunk
-  writes land in a reserved cache margin (see ``Engine``) and their
-  positions do not advance.
+  steps *per slot*.  Each call argmaxes the slots' last prompt rows on
+  the device and hands back only their first tokens.  Slots
+  mid-generation are untouched: their chunk writes land in a reserved
+  cache margin (see ``Engine``) and their positions do not advance.
 * **Device-resident decode loop** — generation runs through
   ``build_decode_loop``: ``step_many(n)`` executes n decode steps inside
   ONE ``lax.scan`` jit call — model step, per-slot sampling (greedy /
@@ -362,7 +363,8 @@ class Engine:
         #: donate their cache argument, so restore re-device_puts)
         self._cache_sh = c_sh
         self.decode = jax.jit(build_serve_step(cfg, ctx))
-        self.prefill = jax.jit(build_prefill_step(cfg, ctx))
+        self.prefill = jax.jit(build_prefill_step(cfg, ctx,
+                                                  first_token=True))
         #: per-block-size cache of jitted fused decode loops
         self._loops: Dict[int, callable] = {}
         #: per-block-size cache of jitted speculative draft→verify loops
@@ -440,8 +442,8 @@ class Engine:
         #: runs of the prefill program, ``prefill_rows`` the lane x token
         #: rows they computed, ``prefill_tokens`` the prompt tokens they
         #: ingested (suffixes, under prefix hits); ``host_fetch_bytes``
-        #: counts the prefill logits and decode-block outputs copied to
-        #: the host (see :meth:`_fetch`).
+        #: counts the prefill first tokens and decode-block outputs
+        #: copied to the host (see :meth:`_fetch`).
         self.counters = {"peak_live": 0, "admitted": 0, "gen_tokens": 0,
                          "queue_wait_s": 0.0, "prefill_calls": 0,
                          "prefill_rows": 0, "prefill_tokens": 0,
@@ -1541,7 +1543,12 @@ class Engine:
         """Batched chunked prefill; ``starts`` (slot -> first token to
         ingest) makes it suffix-only for prefix-cache hits — the chunk
         grid then runs at ``start + c0`` per slot, reading the shared
-        prefix pages through the already-flushed block table."""
+        prefix pages through the already-flushed block table.
+
+        Each chunk's program takes every slot's row of its last prompt
+        token in that chunk and returns the greedy first tokens, a (B,)
+        int32: that, not the chunk's logits, is what is fetched, once
+        per chunk.  Returns slot -> first generated token."""
         chunk = self.prefill_chunk
         starts = starts or {}
         offs = {s: int(starts.get(s, 0)) for s in reqs}
@@ -1561,10 +1568,12 @@ class Engine:
         park = (self.block_tables.shape[1] * self.allocator.page_size
                 - chunk) if (self.paged and offs and max(offs.values()))\
             else None
+        t_last = {s: t.shape[0] - 1 for s, t in sufs.items()}
         first: Dict[int, int] = {}
         for c0 in range(0, padded, chunk):
             if c0 >= plen:
                 break
+            ends = [s for s in sufs if c0 <= t_last[s] < c0 + chunk]
             # live slots keep their own position: their (ignored) writes
             # land at [pos, pos+chunk) inside the margin, never clamped.
             with TraceAnnotation("engine.prefill.dispatch"):
@@ -1574,18 +1583,19 @@ class Engine:
                 else:
                     for s in reqs:
                         cur[s] = min(offs[s] + c0, park)
-                logits, self.cache = self.prefill(
+                last = np.zeros((self.batch,), np.int32)
+                for s in ends:
+                    last[s] = t_last[s] - c0
+                tok, self.cache = self.prefill(
                     self.params, {"tokens": _snap(toks[:, c0:c0 + chunk])},
-                    self.cache, _snap(cur))
+                    self.cache, _snap(cur), _snap(last))
             self.counters["prefill_calls"] += 1
             self.counters["prefill_rows"] += self.batch * chunk
             with TraceAnnotation("engine.prefill.fetch"):
-                logits = self._fetch(logits)
+                tok = self._fetch(tok)
             with TraceAnnotation("engine.prefill.argmax"):
-                for s, t in sufs.items():
-                    t_last = t.shape[0] - 1
-                    if c0 <= t_last < c0 + chunk:
-                        first[s] = int(np.argmax(logits[s, t_last - c0]))
+                for s in ends:
+                    first[s] = int(tok[s])
         self.counters["prefill_tokens"] += sum(t.shape[0]
                                                for t in sufs.values())
         return first

@@ -53,9 +53,12 @@ def prefill_fn(params, batch, cache, cfg: ModelConfig, ctx, *,
     ``pos``: optional (B,) start positions — the chunked-prefill regime
     (each call ingests one prompt chunk; the KV cache continues from
     ``pos`` instead of 0).  ``full_logits=True`` returns logits for every
-    chunk position instead of only the last one, so a serving engine can
-    read each sequence's true last-token logits when prompts end
-    mid-chunk.
+    chunk position instead of only the last one, so each sequence's true
+    last-token logits can be read when prompts end mid-chunk: the
+    serving engine's prefill program reads them on the device and
+    returns only each slot's first token, while the drafter's prefill
+    and ``chip_smoke``'s logit comparison take the full logits
+    (``train.step.build_prefill_step``).
     """
     fam = get_family(cfg)
     if cfg.family in ("encdec", "vlm"):

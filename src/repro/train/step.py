@@ -457,13 +457,26 @@ def build_spec_decode_loop(cfg: ModelConfig, ctx: QuantContext, steps: int,
     return spec_loop
 
 
-def build_prefill_step(cfg: ModelConfig, ctx: QuantContext) -> Callable:
+def build_prefill_step(cfg: ModelConfig, ctx: QuantContext, *,
+                       first_token: bool = False) -> Callable:
     """(params, batch, cache, pos) -> (chunk_logits, cache).
 
-    The chunked-prefill step used by the serving engine: ``pos`` gives
-    each slot's current cache position and the returned logits cover
-    every chunk position (so ragged prompt ends can be read per slot).
-    Pass ``pos=None`` for a whole-prompt prefill from position 0.
+    The chunked-prefill step: ``pos`` gives each slot's current cache
+    position and the returned logits cover every chunk position (so
+    ragged prompt ends can be read per slot).  Pass ``pos=None`` for a
+    whole-prompt prefill from position 0.  This full-logits form serves
+    the drafter's prefill and ``chip_smoke``'s logit comparison.
+
+    ``first_token=True`` builds the serving engine's form instead:
+    (params, batch, cache, pos, last) -> (first_tokens, cache).  ``last``
+    (B,) int32 is each slot's row of its last prompt token within this
+    chunk (any row in ``[0, chunk)`` for a slot whose prompt does not end
+    here); the step gathers that row of the chunk's logits and returns
+    its greedy argmax as a (B,) int32, so only B tokens, not the
+    (B, chunk, vocab) logits, cross to the host.  Ties go to the lowest
+    index, as in ``np.argmax``.  Both forms keep the jitted name
+    ``prefill_step``, by which the benchmark finds the program in a
+    device trace.
 
     Cache-layout agnostic: with a paged cache the chunk's K/V scatter
     through each slot's block table instead of a dense row range, and
@@ -473,8 +486,16 @@ def build_prefill_step(cfg: ModelConfig, ctx: QuantContext) -> Callable:
     """
     from ..models.api import prefill_fn
 
-    def prefill_step(params, batch, cache, pos=None):
-        return prefill_fn(params, batch, cache, cfg, ctx, pos=pos,
-                          full_logits=True)
+    if not first_token:
+        def prefill_step(params, batch, cache, pos=None):
+            return prefill_fn(params, batch, cache, cfg, ctx, pos=pos,
+                              full_logits=True)
+        return prefill_step
+
+    def prefill_step(params, batch, cache, pos, last):
+        logits, cache = prefill_fn(params, batch, cache, cfg, ctx, pos=pos,
+                                   full_logits=True)
+        rows = jnp.take_along_axis(logits, last[:, None, None], axis=1)
+        return jnp.argmax(rows[:, 0], axis=-1).astype(jnp.int32), cache
 
     return prefill_step

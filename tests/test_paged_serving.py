@@ -9,8 +9,9 @@ weights, including requests admitted mid-stream onto freshly recycled
 pages and prompts whose pages are physically non-contiguous.
 
 Plus the allocator's own invariants (hypothesis-stub sweeps), the
-``add_requests`` long-prompt rejection fix, and the engine's admission
-and transfer counters.
+``add_requests`` long-prompt rejection fix, the engine's admission
+and transfer counters, and its first-token prefill program against the
+full-logits prefill step.
 """
 
 import jax
@@ -29,6 +30,7 @@ from repro.launch.paging import PageAllocator
 from repro.launch.serve import Engine, quantize_for_serving
 from repro.models.api import get_family
 from repro.nn.context import QuantContext
+from repro.train.step import build_prefill_step
 
 ARCHS = {"lm": "gemma-2b", "ssm": "mamba2-370m", "hybrid": "zamba2-1.2b"}
 _CACHE = {}
@@ -452,16 +454,16 @@ class TestServingCounters:
         assert c["prefill_calls"] == 2
         assert c["prefill_rows"] == 2 * 2 * 8            # calls x B x chunk
         assert c["prefill_tokens"] == 12 + 16
-        logits = 2 * (2 * 8 * cfg.vocab * 4)             # f32 per call
-        assert c["host_fetch_bytes"] == logits
+        first = 2 * (2 * 4)                # (B,) int32 first tokens per call
+        assert c["host_fetch_bytes"] == first
         # block (2, B) int32 and its live mask; tokens (B, 1), pos (B,)
         # int32; live and fault (B,) bool
         block = 2 * 2 * 4 + 2 * 2 + 2 * 4 + 2 * 4 + 2 + 2
-        assert eng.counters["host_fetch_bytes"] == logits + block
+        assert eng.counters["host_fetch_bytes"] == first + block
         st = eng.stats()
         assert st["prefill_calls"] == 2
         assert st["prefill_useful_share"] == pytest.approx(28 / 32)
-        assert st["host_fetch_bytes"] == logits + block
+        assert st["host_fetch_bytes"] == first + block
 
     def test_prefill_tokens_count_suffixes_under_prefix_hits(self):
         """A prompt whose first two pages are cached ingests only its
@@ -526,7 +528,8 @@ class TestServingCounters:
             if program == "prefill_step":
                 lowered = eng.prefill.lower(
                     eng.params, {"tokens": jnp.zeros((b, 8), jnp.int32)},
-                    eng.cache, jnp.zeros((b,), jnp.int32))
+                    eng.cache, jnp.zeros((b,), jnp.int32),
+                    jnp.zeros((b,), jnp.int32))
             else:
                 lowered = eng._loops[2].lower(  # noqa: SLF001
                     eng.params, eng.cache, jnp.zeros((b, 1), jnp.int32),
@@ -537,3 +540,104 @@ class TestServingCounters:
                     None, jnp.int32(0), jnp.int32(eng.eos_id))
         head = lowered.compile().as_text().splitlines()[0]
         assert head.startswith("HloModule ") and program in head.split()[1]
+
+
+# ===========================================================================
+def _full_logits_spy(eng, seen):
+    """Wrap the engine's prefill program so that each call also records
+    its start positions and the full-logits prefill step's (B, chunk,
+    vocab) output on the same inputs."""
+    full = jax.jit(build_prefill_step(eng.cfg, eng.ctx))
+    inner = eng.prefill
+
+    def prefill(params, batch, cache, pos, last):
+        logits, _ = full(params, batch, cache, pos)
+        seen.append((np.asarray(pos), np.asarray(logits)))
+        return inner(params, batch, cache, pos, last)
+
+    eng.prefill = prefill
+
+
+def _host_argmax_prefill(eng):
+    """Serve the engine's prefill through the full-logits step, with the
+    whole logits block fetched and argmaxed on the host."""
+    full = jax.jit(build_prefill_step(eng.cfg, eng.ctx))
+
+    def prefill(params, batch, cache, pos, last):
+        logits, cache = full(params, batch, cache, pos)
+        logits, last = np.asarray(logits), np.asarray(last)
+        rows = logits[np.arange(last.shape[0]), last]
+        return np.argmax(rows, axis=-1).astype(np.int32), cache
+
+    eng.prefill = prefill
+
+
+class TestFirstTokenPrefill:
+    """The engine's prefill program returns each lane's first token, the
+    argmax of the full-logits prefill step at the lane's last prompt
+    row, wherever in the chunk grid that row falls."""
+
+    CHUNK = 8
+    LENS = {"one_token": (1,), "mid_chunk": (5,), "chunk_end": (8,),
+            "multi_chunk": (20,), "lanes_end_apart": (3, 19)}
+
+    def _engine(self, **kw):
+        cfg, ctx, params, mesh = _setup("lm", "f32")
+        return Engine(cfg, ctx, params, mesh, batch=2, max_len=32,
+                      paged=True, page_size=8, prefill_chunk=self.CHUNK,
+                      **kw)
+
+    def _prefix_requests(self, eng, cfg, full_hit):
+        """Publish a 16-token prompt, then admit it again (full hit,
+        suffix from 15) or with 4 more tokens (suffix from 16), beside
+        an unrelated 11-token prompt that starts from 0."""
+        base = _prompts(cfg, (16,), seed=9)[0]
+        eng.add_requests({0: base}, gen_len=2)
+        eng.step_many(2)
+        eng.retire_finished()
+        tail = base if full_hit else np.concatenate(
+            [base, _prompts(cfg, (4,), seed=10)[0]])
+        reqs = {0: _prompts(cfg, (11,), seed=11)[0], 1: tail}
+        return reqs, {1: 15 if full_hit else 16}
+
+    @pytest.mark.parametrize("case", list(LENS) + [
+        "prefix_suffix", "prefix_full_hit", "greedy_streams"])
+    def test_first_tokens_are_argmax_of_full_logits(self, case):
+        cfg, _, _, mesh = _setup("lm", "f32")
+        with use_mesh(mesh):
+            if case == "greedy_streams":
+                streams = []
+                for host_path in (False, True):
+                    eng = self._engine()
+                    if host_path:
+                        _host_argmax_prefill(eng)
+                    eng.add_requests(dict(enumerate(
+                        _prompts(cfg, (5, 13), seed=12))), gen_len=6)
+                    while eng.live.any():
+                        eng.step_many(3)
+                    streams.append([list(o) for o in eng.outputs])
+                assert streams[0] == streams[1]
+                assert all(len(o) == 6 for o in streams[0])
+                return
+            prefix = case.startswith("prefix")
+            eng = self._engine(prefix_cache=prefix)
+            if prefix:
+                reqs, starts = self._prefix_requests(
+                    eng, cfg, case == "prefix_full_hit")
+            else:
+                reqs = dict(enumerate(_prompts(cfg, self.LENS[case],
+                                               seed=9)))
+                starts = {}
+            hits = eng.counters["prefix_hits"]
+            seen = []
+            _full_logits_spy(eng, seen)
+            eng.add_requests(reqs, gen_len=2)
+        assert eng.counters["prefix_hits"] - hits == int(prefix)
+        assert len(seen) == -(-max(p.shape[0] - starts.get(s, 0)
+                                   for s, p in reqs.items()) // self.CHUNK)
+        for s, p in reqs.items():
+            start = starts.get(s, 0)
+            k, row = divmod(p.shape[0] - 1 - start, self.CHUNK)
+            pos, logits = seen[k]
+            assert pos[s] == start + k * self.CHUNK
+            assert eng.tokens[s, 0] == np.argmax(logits[s, row])
