@@ -441,12 +441,16 @@ class Engine:
         #: call - submit) over admitted requests; ``prefill_calls`` counts
         #: runs of the prefill program, ``prefill_rows`` the lane x token
         #: rows they computed, ``prefill_tokens`` the prompt tokens they
-        #: ingested (suffixes, under prefix hits); ``host_fetch_bytes``
-        #: counts the prefill first tokens and decode-block outputs
-        #: copied to the host (see :meth:`_fetch`).
+        #: ingested (suffixes, under prefix hits); over the lanes with
+        #: prompt rows in a call, ``prefill_kv_tokens`` sums each lane's
+        #: context at the chunk's end (the K/V tokens its rows read) and
+        #: ``prefill_attn_pairs`` its causal query-key pairs;
+        #: ``host_fetch_bytes`` counts the prefill first tokens and
+        #: decode-block outputs copied to the host (see :meth:`_fetch`).
         self.counters = {"peak_live": 0, "admitted": 0, "gen_tokens": 0,
                          "queue_wait_s": 0.0, "prefill_calls": 0,
                          "prefill_rows": 0, "prefill_tokens": 0,
+                         "prefill_kv_tokens": 0, "prefill_attn_pairs": 0,
                          "host_fetch_bytes": 0,
                          "decode_s": 0.0, "verify_steps": 0,
                          "draft_accepted": 0, "preemptions": 0,
@@ -1591,6 +1595,13 @@ class Engine:
                     self.cache, _snap(cur), _snap(last))
             self.counters["prefill_calls"] += 1
             self.counters["prefill_rows"] += self.batch * chunk
+            for s, t in sufs.items():
+                r = min(t.shape[0] - c0, chunk)
+                if r > 0:
+                    p0 = offs[s] + c0
+                    self.counters["prefill_kv_tokens"] += p0 + r
+                    self.counters["prefill_attn_pairs"] += (
+                        r * p0 + r * (r + 1) // 2)
             with TraceAnnotation("engine.prefill.fetch"):
                 tok = self._fetch(tok)
             with TraceAnnotation("engine.prefill.argmax"):
@@ -2354,6 +2365,10 @@ class Engine:
                "prefill_useful_share": (
                    c["prefill_tokens"] / c["prefill_rows"]
                    if c["prefill_rows"] else None),
+               # the paged prefill kernel's useful work: K/V tokens its
+               # prompt rows read and their causal query-key pairs
+               "prefill_kv_tokens": c["prefill_kv_tokens"],
+               "prefill_attn_pairs": c["prefill_attn_pairs"],
                "host_fetch_bytes": c["host_fetch_bytes"]}
         if self.request_log:
             out["ttft_mean_s"] = float(np.mean(

@@ -55,10 +55,9 @@ def prefill_fn(params, batch, cache, cfg: ModelConfig, ctx, *,
     ``pos`` instead of 0).  ``full_logits=True`` returns logits for every
     chunk position instead of only the last one, so each sequence's true
     last-token logits can be read when prompts end mid-chunk: the
-    serving engine's prefill program reads them on the device and
-    returns only each slot's first token, while the drafter's prefill
-    and ``chip_smoke``'s logit comparison take the full logits
-    (``train.step.build_prefill_step``).
+    drafter's prefill and ``chip_smoke``'s logit comparison take them
+    (``train.step.build_prefill_step``); the serving engine's prefill
+    program uses :func:`prefill_first_tokens_fn` instead.
     """
     fam = get_family(cfg)
     if cfg.family in ("encdec", "vlm"):
@@ -66,6 +65,17 @@ def prefill_fn(params, batch, cache, cfg: ModelConfig, ctx, *,
                            full_logits=full_logits)
     return fam.prefill(params, batch["tokens"], cache, cfg, ctx, pos=pos,
                        full_logits=full_logits)
+
+
+def prefill_first_tokens_fn(params, batch, cache, cfg: ModelConfig, ctx, *,
+                            pos, last):
+    """Family-dispatched chunked prefill that returns each slot's greedy
+    first token, a (B,) int32, from its row ``last`` of the chunk, with
+    the new cache.  Families with chunked prefill
+    (:func:`supports_chunked_prefill`) apply the LM head to those B rows
+    only."""
+    return get_family(cfg).prefill_first_tokens(
+        params, batch["tokens"], cache, cfg, ctx, pos=pos, last=last)
 
 
 def decode_fn(params, tokens, cache, pos, cfg: ModelConfig, ctx):

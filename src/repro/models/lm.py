@@ -34,7 +34,7 @@ from .common import cross_entropy
 from .config import ModelConfig
 
 __all__ = ["init", "forward", "loss", "init_cache", "init_paged_cache",
-           "prefill", "decode_step"]
+           "prefill", "prefill_first_tokens", "decode_step"]
 
 
 def _split_layers(cfg: ModelConfig) -> Tuple[int, int]:
@@ -79,10 +79,11 @@ def _moe_body(cfg, ctx, cache_pos):
     return body
 
 
-def forward(params, tokens: jnp.ndarray, cfg: ModelConfig,
-            ctx: QuantContext = DEFAULT_CTX, *, cache=None,
-            cache_pos: Optional[jnp.ndarray] = None):
-    """tokens (B, S) → (logits (B, S, V), new_cache, aux_loss)."""
+def _hidden(params, tokens: jnp.ndarray, cfg: ModelConfig, ctx: QuantContext,
+            cache, cache_pos):
+    """tokens (B, S) → (hidden states after the last layer (B, S, d),
+    new_cache, aux_loss): everything of :func:`forward` but the final
+    norm and the LM head."""
     x = embed(params["embed"], tokens, ctx, scale_by_dim=cfg.embed_scale)
     n_dense, n_moe = _split_layers(cfg)
     remat = cfg.remat if cache is None else "none"
@@ -102,15 +103,26 @@ def forward(params, tokens: jnp.ndarray, cfg: ModelConfig,
                               _moe_body(cfg, ctx, cache_pos), remat=remat,
                               unroll=unroll, per_layer=c)
         new_cache["moe"], aux = nc, aux + a
+    return x, (new_cache if cache is not None else None), aux
 
+
+def _logits(params, x: jnp.ndarray, cfg: ModelConfig, ctx: QuantContext):
+    """Final norm and LM head: hidden states (..., d) → logits (..., V)."""
     x = norm_apply(cfg, params["final_norm"], x)
     if cfg.tie_embeddings:
         logits = unembed(params["embed"], x, ctx)
     else:
         logits = linear(params["head"], x, ctx, path="head")
     from ..dist.constrain import constrain
-    logits = constrain(logits, "dp", None, "tp")
-    return logits, (new_cache if cache is not None else None), aux
+    return constrain(logits, "dp", None, "tp")
+
+
+def forward(params, tokens: jnp.ndarray, cfg: ModelConfig,
+            ctx: QuantContext = DEFAULT_CTX, *, cache=None,
+            cache_pos: Optional[jnp.ndarray] = None):
+    """tokens (B, S) → (logits (B, S, V), new_cache, aux_loss)."""
+    x, new_cache, aux = _hidden(params, tokens, cfg, ctx, cache, cache_pos)
+    return _logits(params, x, cfg, ctx), new_cache, aux
 
 
 def loss(params, batch, cfg: ModelConfig, ctx: QuantContext = DEFAULT_CTX):
@@ -189,6 +201,23 @@ def prefill(params, tokens: jnp.ndarray, cache, cfg: ModelConfig,
     logits, new_cache, _ = forward(params, tokens, cfg, ctx, cache=cache,
                                    cache_pos=start)
     return (logits if full_logits else logits[:, -1:]), new_cache
+
+
+def prefill_first_tokens(params, tokens: jnp.ndarray, cache,
+                         cfg: ModelConfig, ctx: QuantContext = DEFAULT_CTX,
+                         *, pos: jnp.ndarray, last: jnp.ndarray):
+    """Chunked prefill that returns each slot's greedy first token.
+
+    ``last`` (B,) int32: each slot's row of its last prompt token in this
+    chunk.  That row of the hidden states is gathered before the final
+    norm and the LM head, so the head runs on B rows, not on B x chunk,
+    and no (B, chunk, V) array is made.  Returns ((B,) int32 argmax, ties
+    to the lowest index, new_cache).
+    """
+    x, new_cache, _ = _hidden(params, tokens, cfg, ctx, cache, pos)
+    rows = jnp.take_along_axis(x, last[:, None, None], axis=1)
+    logits = _logits(params, rows, cfg, ctx)
+    return jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32), new_cache
 
 
 def decode_step(params, tokens: jnp.ndarray, cache, pos: jnp.ndarray,

@@ -471,10 +471,11 @@ def build_prefill_step(cfg: ModelConfig, ctx: QuantContext, *,
     (params, batch, cache, pos, last) -> (first_tokens, cache).  ``last``
     (B,) int32 is each slot's row of its last prompt token within this
     chunk (any row in ``[0, chunk)`` for a slot whose prompt does not end
-    here); the step gathers that row of the chunk's logits and returns
-    its greedy argmax as a (B,) int32, so only B tokens, not the
-    (B, chunk, vocab) logits, cross to the host.  Ties go to the lowest
-    index, as in ``np.argmax``.  Both forms keep the jitted name
+    here); the step gathers that row of the chunk's final hidden states,
+    applies the final norm and the LM head to those B rows only, and
+    returns their greedy argmax as a (B,) int32, so neither the device
+    nor the host holds the (B, chunk, vocab) logits.  Ties go to the
+    lowest index, as in ``np.argmax``.  Both forms keep the jitted name
     ``prefill_step``, by which the benchmark finds the program in a
     device trace.
 
@@ -484,7 +485,7 @@ def build_prefill_step(cfg: ModelConfig, ctx: QuantContext, *,
     land on the shared trash page.  Same step function, same jit — the
     layout is carried entirely by the cache pytree.
     """
-    from ..models.api import prefill_fn
+    from ..models.api import prefill_first_tokens_fn, prefill_fn
 
     if not first_token:
         def prefill_step(params, batch, cache, pos=None):
@@ -493,9 +494,7 @@ def build_prefill_step(cfg: ModelConfig, ctx: QuantContext, *,
         return prefill_step
 
     def prefill_step(params, batch, cache, pos, last):
-        logits, cache = prefill_fn(params, batch, cache, cfg, ctx, pos=pos,
-                                   full_logits=True)
-        rows = jnp.take_along_axis(logits, last[:, None, None], axis=1)
-        return jnp.argmax(rows[:, 0], axis=-1).astype(jnp.int32), cache
+        return prefill_first_tokens_fn(params, batch, cache, cfg, ctx,
+                                       pos=pos, last=last)
 
     return prefill_step

@@ -485,6 +485,39 @@ class TestServingCounters:
         assert eng.counters["prefill_calls"] - c["prefill_calls"] == 1
         assert eng.counters["prefill_rows"] - c["prefill_rows"] == 2 * 8
 
+    @pytest.mark.parametrize("case", ["fresh", "prefix_suffix"])
+    def test_prefill_kernel_counters(self, case):
+        """Prompts of 12 and 16 tokens in chunks of 8: per call and lane
+        with prompt rows, ``prefill_kv_tokens`` adds the context at the
+        chunk's end (8 + 8, then 12 + 16) and ``prefill_attn_pairs`` r*s +
+        r(r+1)/2 for r rows from position s (36 + 36, then 8*4 + 10 and
+        8*8 + 36), n(n+1)/2 of each whole prompt.  A prefix hit ingests
+        only its suffix: after a 16-token prompt (8 + 16 tokens read), 4
+        rows from position 16 read 20 tokens and make 16*4 + 10 pairs."""
+        cfg, _, _, mesh = _setup("lm", "f32")
+        with use_mesh(mesh):
+            eng = self._engine(prefix_cache=case == "prefix_suffix")
+            if case == "fresh":
+                for p in _prompts(cfg, (12, 16), seed=4):
+                    eng.submit(p, gen_len=4)
+                want = (8 + 8 + 12 + 16, 36 + 36 + (8 * 4 + 10)
+                        + (8 * 8 + 36))
+                assert want[1] == 12 * 13 // 2 + 16 * 17 // 2
+            else:
+                first = _prompts(cfg, (16,), seed=5)[0]
+                eng.submit(first, gen_len=2)
+                eng.try_admit()
+                eng.step_many(2)
+                eng.retire_finished()
+                eng.submit(np.concatenate(
+                    [first, _prompts(cfg, (4,), seed=6)[0]]), gen_len=2)
+                want = (8 + 16 + 20, 16 * 17 // 2 + 16 * 4 + 4 * 5 // 2)
+            eng.try_admit()
+        c = eng.counters
+        assert (c["prefill_kv_tokens"], c["prefill_attn_pairs"]) == want
+        st = eng.stats()
+        assert (st["prefill_kv_tokens"], st["prefill_attn_pairs"]) == want
+
     def test_queue_wait_from_the_clock(self):
         """Requests submitted at t=1 and t=2 and admitted at t=5 waited
         4 s and 3 s; a direct add waited none."""
@@ -641,3 +674,59 @@ class TestFirstTokenPrefill:
             pos, logits = seen[k]
             assert pos[s] == start + k * self.CHUNK
             assert eng.tokens[s, 0] == np.argmax(logits[s, row])
+
+
+# ===========================================================================
+class TestFirstTokenHead:
+    """The first-token prefill program applies the final norm and the LM
+    head to each lane's gathered row only.  At a glm4-shaped smoke size
+    (qkv bias, half-width rotary, 16 query heads over one kv head, an
+    untied head) it returns exactly the argmax of the full-logits
+    program's rows, and holds no (B, chunk, vocab) array."""
+
+    B, CHUNK, VOCAB = 4, 16, 1000
+
+    def _setup(self):
+        import dataclasses
+        cfg = dataclasses.replace(
+            get_config("glm4-9b").smoke(), n_heads=16, n_kv_heads=1,
+            head_dim=16, vocab=self.VOCAB)
+        assert cfg.qkv_bias and cfg.rope_fraction == 0.5
+        assert not cfg.tie_embeddings
+        ctx = QuantContext(compute_dtype=jnp.float32)
+        params = get_family(cfg).init(jax.random.PRNGKey(3), cfg)
+        cache = get_family(cfg).init_cache(cfg, self.B, 64, jnp.float32)
+        rs = np.random.RandomState(13)
+        tokens = jnp.asarray(rs.randint(0, cfg.vocab, (self.B, self.CHUNK)),
+                             jnp.int32)
+        pos = jnp.asarray([0, 16, 5, 32], jnp.int32)
+        last = jnp.asarray([0, 7, 15, 11], jnp.int32)
+        return cfg, ctx, params, ({"tokens": tokens}, cache, pos, last)
+
+    def test_first_tokens_equal_argmax_of_gathered_full_logits(self):
+        cfg, ctx, params, (batch, cache, pos, last) = self._setup()
+        first = jax.jit(build_prefill_step(cfg, ctx, first_token=True))
+        full = jax.jit(build_prefill_step(cfg, ctx))
+        tok, c1 = first(params, batch, cache, pos, last)
+        logits, c2 = full(params, batch, cache, pos)
+        rows = np.asarray(logits)[np.arange(self.B), np.asarray(last)]
+        assert tok.dtype == jnp.int32 and tok.shape == (self.B,)
+        np.testing.assert_array_equal(np.asarray(tok),
+                                      np.argmax(rows, axis=-1))
+        for a, b in zip(jax.tree_util.tree_leaves(c1),
+                        jax.tree_util.tree_leaves(c2)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    @pytest.mark.parametrize("form", ["first_token", "full_logits"])
+    def test_no_chunk_by_vocab_array(self, form):
+        """The full-logits form, the control, does hold one."""
+        cfg, ctx, params, (batch, cache, pos, last) = self._setup()
+        if form == "first_token":
+            step = jax.jit(build_prefill_step(cfg, ctx, first_token=True))
+            args = (params, batch, cache, pos, last)
+        else:
+            step = jax.jit(build_prefill_step(cfg, ctx))
+            args = (params, batch, cache, pos)
+        hlo = step.lower(*args).compile().as_text()
+        shape = f"[{self.B},{self.CHUNK},{self.VOCAB}]"
+        assert (shape in hlo) == (form == "full_logits")

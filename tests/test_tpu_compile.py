@@ -91,6 +91,20 @@ def test_paged_attention(one_chip, s, kv_split, pages_per_step):
         *_paged_args(one_chip, s))
 
 
+@pytest.mark.parametrize("s", [1, 128], ids=["decode", "prefill_chunk"])
+def test_paged_attention_glm4_geometry(one_chip, s):
+    """glm4-9b's: 32 q heads over 2 kv heads of 128 (16 per kv head), a
+    520-page table for 8192-token contexts, 3800 pages of 16 tokens, at
+    the knobs the engine resolves for it (kv_split 16, pages_per_step 8)."""
+    args = (_spec((B, 32, s, DH), jnp.bfloat16, one_chip),
+            _spec((3801, 2, PS, DH), jnp.float32, one_chip),
+            _spec((3801, 2, PS, DH), jnp.float32, one_chip),
+            _spec((B, 520), jnp.int32, one_chip),
+            _spec((B,), jnp.int32, one_chip))
+    assert _has_kernel(lambda *a: paged_attention_pallas(
+        *a, kv_split=16, pages_per_step=8), *args)
+
+
 def test_flash_attention(one_chip):
     q = _spec((1, HQ, 2048, DH), jnp.bfloat16, one_chip)
     kv = _spec((1, HKV, 2048, DH), jnp.bfloat16, one_chip)
