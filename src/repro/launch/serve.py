@@ -14,13 +14,16 @@ realized at framework level, as a fused quantized dense pipeline:
   activation execute as one ``qmatmul`` Pallas launch (see
   :mod:`repro.kernels.qmatmul`), one HBM pass instead of three.
 * **Batched chunked prefill** — prompt ingestion runs through
-  ``build_prefill_step``: all fresh slots advance together, one
-  full-batch model call per ``prefill_chunk`` tokens, i.e.
-  O(prompt_len / chunk) steps total instead of O(prompt_len) decode
-  steps *per slot*.  Each call argmaxes the slots' last prompt rows on
-  the device and hands back only their first tokens.  Slots
-  mid-generation are untouched: their chunk writes land in a reserved
-  cache margin (see ``Engine``) and their positions do not advance.
+  ``build_prefill_step``: all fresh slots advance together, one model
+  call per ``prefill_chunk`` tokens, i.e. O(prompt_len / chunk) steps
+  total instead of O(prompt_len) decode steps *per slot*.  Each call
+  argmaxes the slots' last prompt rows on the device and hands back
+  only their first tokens.  On the paged cache a call runs over only
+  the lanes with prompt rows in its chunk, at a power-of-two width;
+  slots mid-generation are not in it.  On the dense cache every call
+  runs over the whole batch, and slots mid-generation write (ignored)
+  rows into a reserved cache margin (see ``Engine``) without their
+  positions advancing.
 * **Device-resident decode loop** — generation runs through
   ``build_decode_loop``: ``step_many(n)`` executes n decode steps inside
   ONE ``lax.scan`` jit call — model step, per-slot sampling (greedy /
@@ -85,6 +88,7 @@ from __future__ import annotations
 
 import argparse
 import contextlib
+import copy
 import dataclasses
 import os
 import time
@@ -174,11 +178,14 @@ class Engine:
 
     Cache layout note: the KV cache is allocated with ``prefill_chunk``
     margin rows beyond ``max_len``.  During a mid-flight refill the
-    chunked prefill runs full-batch, so slots that are still generating
-    receive (ignored) writes at their current position; the margin
-    guarantees those writes can never clamp back into valid rows, and
-    the per-slot visibility mask (`kvpos <= qpos`) keeps them invisible
-    until decode overwrites them.
+    dense cache's chunked prefill runs full-batch, so slots that are
+    still generating receive (ignored) writes at their current
+    position; the margin guarantees those writes can never clamp back
+    into valid rows, and the per-slot visibility mask (`kvpos <= qpos`)
+    keeps them invisible until decode overwrites them.  The paged
+    cache's prefill leaves such slots out of the call (see
+    ``_prefill_chunked``); its margin holds the rows past a prompt's end
+    in its last chunk.
     """
 
     def __init__(self, cfg, ctx, params, mesh, *, batch: int, max_len: int,
@@ -365,6 +372,8 @@ class Engine:
         self.decode = jax.jit(build_serve_step(cfg, ctx))
         self.prefill = jax.jit(build_prefill_step(cfg, ctx,
                                                   first_token=True))
+        if self.paged and self.chunked:
+            self._warm_prefill()
         #: per-block-size cache of jitted fused decode loops
         self._loops: Dict[int, callable] = {}
         #: per-block-size cache of jitted speculative draft→verify loops
@@ -445,13 +454,14 @@ class Engine:
         #: prompt rows in a call, ``prefill_kv_tokens`` sums each lane's
         #: context at the chunk's end (the K/V tokens its rows read) and
         #: ``prefill_attn_pairs`` its causal query-key pairs;
-        #: ``host_fetch_bytes`` counts the prefill first tokens and
+        #: ``prefill_width_calls`` maps each call width (rows per call) to
+        #: its calls; ``host_fetch_bytes`` counts the prefill first tokens and
         #: decode-block outputs copied to the host (see :meth:`_fetch`).
         self.counters = {"peak_live": 0, "admitted": 0, "gen_tokens": 0,
                          "queue_wait_s": 0.0, "prefill_calls": 0,
                          "prefill_rows": 0, "prefill_tokens": 0,
                          "prefill_kv_tokens": 0, "prefill_attn_pairs": 0,
-                         "host_fetch_bytes": 0,
+                         "prefill_width_calls": {}, "host_fetch_bytes": 0,
                          "decode_s": 0.0, "verify_steps": 0,
                          "draft_accepted": 0, "preemptions": 0,
                          "cancellations": 0, "timeouts": 0, "failures": 0,
@@ -1543,70 +1553,94 @@ class Engine:
         self.counters["peak_live"] = max(self.counters["peak_live"],
                                          int(self.live.sum()))
 
+    def _prefill_width(self, n: int) -> int:
+        """Rows of a paged prefill call over ``n`` lanes: the smallest
+        power of two >= n, at most ``batch`` (1, 2, 4, 8 at 8 lanes)."""
+        return min(1 << (n - 1).bit_length(), self.batch)
+
+    def _warm_prefill(self) -> None:
+        """Compile the paged prefill program at every width it is called
+        at, so that no admission compiles one.  The calls hold pad rows
+        alone, whose writes land on the trash page only.  The first call
+        moves the cache from the placement it was built with to that of
+        a program's output, the one every later call sees."""
+        widths = sorted({self._prefill_width(n)
+                         for n in range(1, self.batch + 1)})
+        with use_mesh(self.mesh):
+            for w in [widths[0], *widths]:
+                zero = np.zeros((w,), np.int32)
+                _, self.cache = self.prefill(
+                    self.params,
+                    {"tokens": _snap(np.zeros((w, self.prefill_chunk),
+                                              np.int32))},
+                    self.cache, _snap(zero), _snap(zero),
+                    _snap(np.full((w,), self.batch, np.int32)))
+
     def _prefill_chunked(self, reqs, starts=None) -> Dict[int, int]:
         """Batched chunked prefill; ``starts`` (slot -> first token to
         ingest) makes it suffix-only for prefix-cache hits — the chunk
         grid then runs at ``start + c0`` per slot, reading the shared
         prefix pages through the already-flushed block table.
 
-        Each chunk's program takes every slot's row of its last prompt
-        token in that chunk and returns the greedy first tokens, a (B,)
-        int32: that, not the chunk's logits, is what is fetched, once
-        per chunk.  Returns slot -> first generated token."""
+        Width rule: on the paged cache, chunk ``c0`` runs over only the
+        n lanes whose suffix still has rows in ``[c0, c0 + chunk)``, at
+        width ``_prefill_width(n)``; the rows past n are pad rows, all
+        trash page.  Lanes that decode, and lanes whose suffix has
+        ended, are not in the call.  The dense cache has no block table
+        to select lanes by: each of its calls runs over all B lanes, the
+        live ones writing (ignored) rows at their own position inside the
+        margin.
+
+        Each call takes each row's last prompt token in its chunk and
+        returns the rows' greedy first tokens, a (W,) int32: that, not
+        the chunk's logits, is what is fetched, once per chunk.  Returns
+        slot -> first generated token."""
         chunk = self.prefill_chunk
         starts = starts or {}
-        offs = {s: int(starts.get(s, 0)) for s in reqs}
-        sufs = {s: p[offs[s]:] for s, p in reqs.items()}
-        plen = max(t.shape[0] for t in sufs.values())
-        padded = -(-plen // chunk) * chunk      # one compile per chunk width
-        toks = np.zeros((self.batch, padded), np.int32)
-        for s, t in sufs.items():
-            toks[s, :t.shape[0]] = t
-        fresh = np.fromiter(sorted(reqs), np.int64)
-        # slots whose (shorter) suffix is exhausted park at the LAST
-        # full chunk inside the table — their garbage writes land at
-        # positions >= max_len (the margin region, never attendable and
-        # below no slot's committed watermark) instead of clamping into
-        # real rows.  Offsets exist only in paged+prefix mode, where
-        # width * page_size >= max_len + margin >= max_len + chunk.
-        park = (self.block_tables.shape[1] * self.allocator.page_size
-                - chunk) if (self.paged and offs and max(offs.values()))\
-            else None
-        t_last = {s: t.shape[0] - 1 for s, t in sufs.items()}
+        sufs = {s: p[starts.get(s, 0):] for s, p in reqs.items()}
         first: Dict[int, int] = {}
-        for c0 in range(0, padded, chunk):
-            if c0 >= plen:
-                break
-            ends = [s for s in sufs if c0 <= t_last[s] < c0 + chunk]
-            # live slots keep their own position: their (ignored) writes
-            # land at [pos, pos+chunk) inside the margin, never clamped.
+        for c0 in range(0, max(t.shape[0] for t in sufs.values()), chunk):
+            now = [s for s in sorted(sufs) if sufs[s].shape[0] > c0]
             with TraceAnnotation("engine.prefill.dispatch"):
-                cur = self.pos.copy()
-                if park is None:
-                    cur[fresh] = c0
+                if self.paged:
+                    rows = np.full((self._prefill_width(len(now)),),
+                                   self.batch, np.int32)
+                    rows[:len(now)] = now
+                    cur = np.zeros(rows.shape, np.int32)
                 else:
-                    for s in reqs:
-                        cur[s] = min(offs[s] + c0, park)
-                last = np.zeros((self.batch,), np.int32)
-                for s in ends:
-                    last[s] = t_last[s] - c0
+                    rows = np.arange(self.batch, dtype=np.int32)
+                    cur = self.pos.copy()
+                toks = np.zeros((rows.shape[0], chunk), np.int32)
+                last = np.zeros(rows.shape, np.int32)
+                ends = {}                       # row -> slot ending here
+                for i, s in enumerate(rows.tolist()):
+                    if s not in sufs:
+                        continue
+                    t = sufs[s][c0:c0 + chunk]
+                    cur[i] = starts.get(s, 0) + c0
+                    toks[i, :t.shape[0]] = t
+                    last[i] = max(t.shape[0] - 1, 0)
+                    if 0 < sufs[s].shape[0] - c0 <= chunk:
+                        ends[i] = s
                 tok, self.cache = self.prefill(
-                    self.params, {"tokens": _snap(toks[:, c0:c0 + chunk])},
-                    self.cache, _snap(cur), _snap(last))
+                    self.params, {"tokens": _snap(toks)}, self.cache,
+                    _snap(cur), _snap(last),
+                    _snap(rows) if self.paged else None)
             self.counters["prefill_calls"] += 1
-            self.counters["prefill_rows"] += self.batch * chunk
-            for s, t in sufs.items():
-                r = min(t.shape[0] - c0, chunk)
-                if r > 0:
-                    p0 = offs[s] + c0
-                    self.counters["prefill_kv_tokens"] += p0 + r
-                    self.counters["prefill_attn_pairs"] += (
-                        r * p0 + r * (r + 1) // 2)
+            self.counters["prefill_rows"] += toks.size
+            widths = self.counters["prefill_width_calls"]
+            widths[rows.shape[0]] = widths.get(rows.shape[0], 0) + 1
+            for s in now:
+                r = min(sufs[s].shape[0] - c0, chunk)
+                p0 = starts.get(s, 0) + c0
+                self.counters["prefill_kv_tokens"] += p0 + r
+                self.counters["prefill_attn_pairs"] += (
+                    r * p0 + r * (r + 1) // 2)
             with TraceAnnotation("engine.prefill.fetch"):
                 tok = self._fetch(tok)
             with TraceAnnotation("engine.prefill.argmax"):
-                for s in ends:
-                    first[s] = int(tok[s])
+                for i, s in ends.items():
+                    first[s] = int(tok[i])
         self.counters["prefill_tokens"] += sum(t.shape[0]
                                                for t in sufs.values())
         return first
@@ -2124,7 +2158,7 @@ class Engine:
             "results": {k: {"status": v["status"],
                             "tokens": list(v["tokens"])}
                         for k, v in self.results.items()},
-            "counters": dict(self.counters),
+            "counters": copy.deepcopy(self.counters),
             "request_log": [dict(r) for r in self.request_log],
         }
         if self.paged:
@@ -2185,7 +2219,8 @@ class Engine:
         self.results = {k: {"status": v["status"],
                             "tokens": list(v["tokens"])}
                         for k, v in snap["results"].items()}
-        self.counters = dict(self.counters, **snap["counters"])
+        self.counters = dict(self.counters,
+                             **copy.deepcopy(snap["counters"]))
         self.request_log = [dict(r) for r in snap["request_log"]]
         if self.paged:
             self.allocator.load_state(snap["allocator"])
@@ -2369,6 +2404,8 @@ class Engine:
                # prompt rows read and their causal query-key pairs
                "prefill_kv_tokens": c["prefill_kv_tokens"],
                "prefill_attn_pairs": c["prefill_attn_pairs"],
+               # calls of the prefill program at each width (rows)
+               "prefill_width_calls": dict(c["prefill_width_calls"]),
                "host_fetch_bytes": c["host_fetch_bytes"]}
         if self.request_log:
             out["ttft_mean_s"] = float(np.mean(

@@ -68,14 +68,16 @@ def prefill_fn(params, batch, cache, cfg: ModelConfig, ctx, *,
 
 
 def prefill_first_tokens_fn(params, batch, cache, cfg: ModelConfig, ctx, *,
-                            pos, last):
-    """Family-dispatched chunked prefill that returns each slot's greedy
-    first token, a (B,) int32, from its row ``last`` of the chunk, with
+                            pos, last, lanes=None):
+    """Family-dispatched chunked prefill that returns each row's greedy
+    first token, a (W,) int32, from its row ``last`` of the chunk, with
     the new cache.  Families with chunked prefill
-    (:func:`supports_chunked_prefill`) apply the LM head to those B rows
-    only."""
+    (:func:`supports_chunked_prefill`) apply the LM head to those W rows
+    only; ``lanes`` selects the paged cache's lanes the rows belong to
+    (see ``lm.prefill_first_tokens``)."""
     return get_family(cfg).prefill_first_tokens(
-        params, batch["tokens"], cache, cfg, ctx, pos=pos, last=last)
+        params, batch["tokens"], cache, cfg, ctx, pos=pos, last=last,
+        lanes=lanes)
 
 
 def decode_fn(params, tokens, cache, pos, cfg: ModelConfig, ctx):
@@ -132,7 +134,11 @@ def set_block_table(cache, bt):
 
     def repl(path, leaf):
         if getattr(path[-1], "key", None) == "block_table":
-            return jnp.broadcast_to(bt, leaf.shape)
+            new = jnp.broadcast_to(bt, leaf.shape)
+            # a placed table keeps its placement, so the programs that
+            # read the cache next see it as the last one left it
+            return (jax.device_put(new, leaf.sharding)
+                    if getattr(leaf, "committed", False) else new)
         return leaf
 
     return jax.tree_util.tree_map_with_path(repl, cache)

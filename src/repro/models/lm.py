@@ -23,7 +23,8 @@ import jax
 import jax.numpy as jnp
 
 from ..nn.attention import (gqa_cache_spec, gqa_paged_cache_spec,
-                            mla_cache_spec, mla_paged_cache_spec)
+                            mla_cache_spec, mla_paged_cache_spec,
+                            select_lanes)
 from ..nn.blocks import (dense_block_apply, dense_block_init, moe_block_apply,
                          moe_block_init, norm_apply, norm_init, scan_apply,
                          stack_init)
@@ -79,11 +80,25 @@ def _moe_body(cfg, ctx, cache_pos):
     return body
 
 
+def _over_lanes(body, lanes):
+    """``body`` run over the lanes ``lanes`` of its layer's paged cache:
+    the layer's block table is narrowed to them inside the layer scan,
+    a (W, NP) table rather than an (L, W, NP) copy, and the layer's
+    (B, NP) table is handed back as it came in."""
+    if lanes is None:
+        return body
+
+    def run(p_l, x, cache_l):
+        x2, new_c, aux = body(p_l, x, select_lanes(cache_l, lanes))
+        return x2, dict(new_c, block_table=cache_l["block_table"]), aux
+    return run
+
+
 def _hidden(params, tokens: jnp.ndarray, cfg: ModelConfig, ctx: QuantContext,
-            cache, cache_pos):
+            cache, cache_pos, lanes=None):
     """tokens (B, S) → (hidden states after the last layer (B, S, d),
     new_cache, aux_loss): everything of :func:`forward` but the final
-    norm and the LM head."""
+    norm and the LM head.  ``lanes``: see :func:`prefill_first_tokens`."""
     x = embed(params["embed"], tokens, ctx, scale_by_dim=cfg.embed_scale)
     n_dense, n_moe = _split_layers(cfg)
     remat = cfg.remat if cache is None else "none"
@@ -93,14 +108,14 @@ def _hidden(params, tokens: jnp.ndarray, cfg: ModelConfig, ctx: QuantContext,
 
     if n_dense:
         c = cache.get("dense") if cache else None
-        x, nc, a = scan_apply(params["dense"], x,
-                              _dense_body(cfg, ctx, cache_pos), remat=remat,
+        body = _over_lanes(_dense_body(cfg, ctx, cache_pos), lanes)
+        x, nc, a = scan_apply(params["dense"], x, body, remat=remat,
                               unroll=unroll, per_layer=c)
         new_cache["dense"], aux = nc, aux + a
     if n_moe:
         c = cache.get("moe") if cache else None
-        x, nc, a = scan_apply(params["moe"], x,
-                              _moe_body(cfg, ctx, cache_pos), remat=remat,
+        body = _over_lanes(_moe_body(cfg, ctx, cache_pos), lanes)
+        x, nc, a = scan_apply(params["moe"], x, body, remat=remat,
                               unroll=unroll, per_layer=c)
         new_cache["moe"], aux = nc, aux + a
     return x, (new_cache if cache is not None else None), aux
@@ -205,16 +220,29 @@ def prefill(params, tokens: jnp.ndarray, cache, cfg: ModelConfig,
 
 def prefill_first_tokens(params, tokens: jnp.ndarray, cache,
                          cfg: ModelConfig, ctx: QuantContext = DEFAULT_CTX,
-                         *, pos: jnp.ndarray, last: jnp.ndarray):
-    """Chunked prefill that returns each slot's greedy first token.
+                         *, pos: jnp.ndarray, last: jnp.ndarray,
+                         lanes: Optional[jnp.ndarray] = None):
+    """Chunked prefill that returns each row's greedy first token.
 
-    ``last`` (B,) int32: each slot's row of its last prompt token in this
+    ``tokens`` (W, chunk), ``pos`` and ``last`` (W,) give W rows: their
+    tokens, start positions, and each row's last prompt token in this
     chunk.  That row of the hidden states is gathered before the final
-    norm and the LM head, so the head runs on B rows, not on B x chunk,
-    and no (B, chunk, V) array is made.  Returns ((B,) int32 argmax, ties
-    to the lowest index, new_cache).
+    norm and the LM head, so the head runs on W rows, not on W x chunk,
+    and no (W, chunk, V) array is made.
+
+    ``lanes`` (W,) int32, on a paged cache: the lane of each row.  The
+    call then runs over those W rows alone: each layer's block table is
+    narrowed to them inside the layer scan
+    (:func:`~repro.nn.attention.select_lanes`), and an index past the
+    last lane is a pad row whose table is all trash page, so its writes
+    land there alone and its token means nothing.  Other lanes are
+    neither written nor read.  The returned cache holds the updated
+    pages with the (L, B, NP) block tables that came in.
+    ``None``: the rows are the cache's lanes in order (a dense cache
+    always).  Returns ((W,) int32 argmax, ties to the lowest index,
+    new_cache).
     """
-    x, new_cache, _ = _hidden(params, tokens, cfg, ctx, cache, pos)
+    x, new_cache, _ = _hidden(params, tokens, cfg, ctx, cache, pos, lanes)
     rows = jnp.take_along_axis(x, last[:, None, None], axis=1)
     logits = _logits(params, rows, cfg, ctx)
     return jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32), new_cache

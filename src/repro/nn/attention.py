@@ -73,7 +73,7 @@ def _constrain_heads(t: jnp.ndarray, role: str = "q") -> jnp.ndarray:
 __all__ = ["AttnDims", "gqa_init", "gqa_apply", "gqa_cache_spec",
            "gqa_paged_cache_spec", "gqa_project_kv", "MLADims", "mla_init",
            "mla_apply", "mla_cache_spec", "mla_paged_cache_spec",
-           "paged_kernel_reads"]
+           "paged_kernel_reads", "select_lanes"]
 
 
 def paged_kernel_reads(ctx: QuantContext, pages, *,
@@ -178,6 +178,25 @@ def gqa_paged_cache_spec(d: AttnDims, batch: int, num_pages: int,
     return {"pages": pages,
             "block_table": jnp.full((batch, table_width), num_pages,
                                     jnp.int32)}
+
+
+def select_lanes(cache, lanes: jnp.ndarray):
+    """The paged ``cache`` as a call over the lanes ``lanes`` (W,) int32
+    sees it: every ``block_table`` leaf (..., B, NP) narrowed to those
+    rows, in that order.  An index past the last lane is a pad row: its
+    table is all trash page (the pool's last page), so its writes land
+    there alone.  Page pools carry no lane axis and pass through whole.
+    A dense cache has no table to narrow and is refused."""
+    if not isinstance(cache, dict):
+        raise ValueError("a lane selection narrows block tables; a dense "
+                         "cache has none")
+    if "block_table" not in cache:
+        return {k: select_lanes(c, lanes) for k, c in cache.items()}
+    bt = cache["block_table"]
+    lead = bt.ndim - 2                   # stacked layers lead both leaves
+    trash = jax.tree_util.tree_leaves(cache["pages"])[0].shape[lead] - 1
+    return dict(cache, block_table=jnp.take(bt, lanes, axis=lead,
+                                            mode="fill", fill_value=trash))
 
 
 def _page_coords(bt: jnp.ndarray, pos: jnp.ndarray, s: int, page_size: int):

@@ -468,16 +468,25 @@ def build_prefill_step(cfg: ModelConfig, ctx: QuantContext, *,
     the drafter's prefill and ``chip_smoke``'s logit comparison.
 
     ``first_token=True`` builds the serving engine's form instead:
-    (params, batch, cache, pos, last) -> (first_tokens, cache).  ``last``
-    (B,) int32 is each slot's row of its last prompt token within this
-    chunk (any row in ``[0, chunk)`` for a slot whose prompt does not end
-    here); the step gathers that row of the chunk's final hidden states,
-    applies the final norm and the LM head to those B rows only, and
-    returns their greedy argmax as a (B,) int32, so neither the device
-    nor the host holds the (B, chunk, vocab) logits.  Ties go to the
-    lowest index, as in ``np.argmax``.  Both forms keep the jitted name
-    ``prefill_step``, by which the benchmark finds the program in a
-    device trace.
+    (params, batch, cache, pos, last, lanes=None) -> (first_tokens,
+    cache).  The W rows of ``batch["tokens"]`` (W, chunk) start at
+    ``pos`` (W,); ``last`` (W,) int32 is each row's last prompt token
+    within this chunk (any row in ``[0, chunk)`` for a row whose prompt
+    does not end here); the step gathers that row of the chunk's final
+    hidden states, applies the final norm and the LM head to those W
+    rows only, and returns their greedy argmax as a (W,) int32, so
+    neither the device nor the host holds the (W, chunk, vocab) logits.
+    Ties go to the lowest index, as in ``np.argmax``.  ``lanes`` (W,)
+    int32, on a paged cache, names the lane of each row: the step runs
+    over those lanes alone, each block table narrowed to their rows
+    inside the program; an index past the last lane is a pad row whose
+    table is all trash page, so its writes land there alone and its
+    token means nothing.  The cache argument keeps its pytree, shapes
+    and placement, and the returned cache carries the (L, B, NP) block
+    tables that came in.  ``lanes=None`` runs over every lane of the
+    cache in order (the dense cache's only form).  Both forms keep the
+    jitted name ``prefill_step``, by which the benchmark finds the
+    program in a device trace.
 
     Cache-layout agnostic: with a paged cache the chunk's K/V scatter
     through each slot's block table instead of a dense row range, and
@@ -493,8 +502,8 @@ def build_prefill_step(cfg: ModelConfig, ctx: QuantContext, *,
                               full_logits=True)
         return prefill_step
 
-    def prefill_step(params, batch, cache, pos, last):
+    def prefill_step(params, batch, cache, pos, last, lanes=None):
         return prefill_first_tokens_fn(params, batch, cache, cfg, ctx,
-                                       pos=pos, last=last)
+                                       pos=pos, last=last, lanes=lanes)
 
     return prefill_step

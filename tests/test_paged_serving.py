@@ -10,9 +10,12 @@ pages and prompts whose pages are physically non-contiguous.
 
 Plus the allocator's own invariants (hypothesis-stub sweeps), the
 ``add_requests`` long-prompt rejection fix, the engine's admission
-and transfer counters, and its first-token prefill program against the
-full-logits prefill step.
+and transfer counters, its first-token prefill program against the
+full-logits prefill step, and its lane-selected prefill calls against
+full-width ones.
 """
+
+import dataclasses
 
 import jax
 import jax.numpy as jnp
@@ -29,6 +32,7 @@ from repro.launch.mesh import make_local_mesh
 from repro.launch.paging import PageAllocator
 from repro.launch.serve import Engine, quantize_for_serving
 from repro.models.api import get_family
+from repro.nn.attention import select_lanes
 from repro.nn.context import QuantContext
 from repro.train.step import build_prefill_step
 
@@ -441,7 +445,7 @@ class TestServingCounters:
 
     def test_prefill_and_fetch_counters(self):
         """Two prompts of 12 and 16 tokens: two chunks of 8 over both
-        lanes, then one decode block of 2 steps."""
+        lanes (width 2), then one decode block of 2 steps."""
         cfg, _, _, mesh = _setup("lm", "f32")
         prompts = _prompts(cfg, (12, 16), seed=4)
         with use_mesh(mesh):
@@ -452,9 +456,10 @@ class TestServingCounters:
             c = dict(eng.counters)
             eng.step_many(2)
         assert c["prefill_calls"] == 2
-        assert c["prefill_rows"] == 2 * 2 * 8            # calls x B x chunk
+        assert c["prefill_rows"] == 2 * 2 * 8            # calls x W x chunk
+        assert c["prefill_width_calls"] == {2: 2}
         assert c["prefill_tokens"] == 12 + 16
-        first = 2 * (2 * 4)                # (B,) int32 first tokens per call
+        first = 2 * (2 * 4)                # (W,) int32 first tokens per call
         assert c["host_fetch_bytes"] == first
         # block (2, B) int32 and its live mask; tokens (B, 1), pos (B,)
         # int32; live and fault (B,) bool
@@ -462,12 +467,13 @@ class TestServingCounters:
         assert eng.counters["host_fetch_bytes"] == first + block
         st = eng.stats()
         assert st["prefill_calls"] == 2
+        assert st["prefill_width_calls"] == {2: 2}
         assert st["prefill_useful_share"] == pytest.approx(28 / 32)
         assert st["host_fetch_bytes"] == first + block
 
     def test_prefill_tokens_count_suffixes_under_prefix_hits(self):
         """A prompt whose first two pages are cached ingests only its
-        suffix: 4 tokens in one chunk."""
+        suffix: 4 tokens in one chunk, over its own lane alone."""
         cfg, _, _, mesh = _setup("lm", "f32")
         first = _prompts(cfg, (16,), seed=5)[0]
         second = np.concatenate([first, _prompts(cfg, (4,), seed=6)[0]])
@@ -483,7 +489,10 @@ class TestServingCounters:
         assert eng.counters["prefix_hits"] == c["prefix_hits"] + 1
         assert eng.counters["prefill_tokens"] - c["prefill_tokens"] == 4
         assert eng.counters["prefill_calls"] - c["prefill_calls"] == 1
-        assert eng.counters["prefill_rows"] - c["prefill_rows"] == 2 * 8
+        assert eng.counters["prefill_rows"] - c["prefill_rows"] == 1 * 8
+        # the first prompt's two chunks and the suffix's one, each at
+        # width 1
+        assert eng.counters["prefill_width_calls"] == {1: 3}
 
     @pytest.mark.parametrize("case", ["fresh", "prefix_suffix"])
     def test_prefill_kernel_counters(self, case):
@@ -577,18 +586,26 @@ class TestServingCounters:
 
 # ===========================================================================
 def _full_logits_spy(eng, seen):
-    """Wrap the engine's prefill program so that each call also records
-    its start positions and the full-logits prefill step's (B, chunk,
-    vocab) output on the same inputs."""
+    """Wrap the engine's prefill program so that each call also records,
+    for each lane in it, its start position and the full-logits prefill
+    step's (chunk, vocab) rows on the same inputs."""
     full = jax.jit(build_prefill_step(eng.cfg, eng.ctx))
     inner = eng.prefill
 
-    def prefill(params, batch, cache, pos, last):
-        logits, _ = full(params, batch, cache, pos)
-        seen.append((np.asarray(pos), np.asarray(logits)))
-        return inner(params, batch, cache, pos, last)
+    def prefill(params, batch, cache, pos, last, lanes):
+        logits, _ = full(params, batch, select_lanes(cache, lanes), pos)
+        seen.append({s: (p, rows) for s, p, rows in zip(
+            np.asarray(lanes).tolist(), np.asarray(pos).tolist(),
+            np.asarray(logits)) if s < eng.batch})
+        return inner(params, batch, cache, pos, last, lanes)
 
     eng.prefill = prefill
+
+
+def _keep_block_tables(new, old):
+    """``new`` with the block tables of ``old``."""
+    return jax.tree_util.tree_map_with_path(
+        lambda p, n, o: o if p[-1].key == "block_table" else n, new, old)
 
 
 def _host_argmax_prefill(eng):
@@ -596,11 +613,12 @@ def _host_argmax_prefill(eng):
     whole logits block fetched and argmaxed on the host."""
     full = jax.jit(build_prefill_step(eng.cfg, eng.ctx))
 
-    def prefill(params, batch, cache, pos, last):
-        logits, cache = full(params, batch, cache, pos)
+    def prefill(params, batch, cache, pos, last, lanes):
+        logits, new = full(params, batch, select_lanes(cache, lanes), pos)
         logits, last = np.asarray(logits), np.asarray(last)
         rows = logits[np.arange(last.shape[0]), last]
-        return np.argmax(rows, axis=-1).astype(np.int32), cache
+        return (np.argmax(rows, axis=-1).astype(np.int32),
+                _keep_block_tables(new, cache))
 
     eng.prefill = prefill
 
@@ -671,9 +689,9 @@ class TestFirstTokenPrefill:
         for s, p in reqs.items():
             start = starts.get(s, 0)
             k, row = divmod(p.shape[0] - 1 - start, self.CHUNK)
-            pos, logits = seen[k]
-            assert pos[s] == start + k * self.CHUNK
-            assert eng.tokens[s, 0] == np.argmax(logits[s, row])
+            pos, logits = seen[k][s]
+            assert pos == start + k * self.CHUNK
+            assert eng.tokens[s, 0] == np.argmax(logits[row])
 
 
 # ===========================================================================
@@ -730,3 +748,146 @@ class TestFirstTokenHead:
         hlo = step.lower(*args).compile().as_text()
         shape = f"[{self.B},{self.CHUNK},{self.VOCAB}]"
         assert (shape in hlo) == (form == "full_logits")
+
+
+# ===========================================================================
+def _against_full_width(eng, log):
+    """Wrap the engine's prefill program so that each call is also run at
+    full width on the same cache: the call's rows in their lanes' rows,
+    every other lane at its position with zero tokens, as the dense
+    engine runs a call.  Logs (lanes, tokens, full-width tokens, cache
+    in, cache out, full-width cache out) per call, on the host."""
+    inner = eng.prefill
+
+    def prefill(params, batch, cache, pos, last, lanes):
+        sel = np.asarray(lanes)[np.asarray(lanes) < eng.batch]
+        n = sel.shape[0]
+        toks = np.zeros((eng.batch, batch["tokens"].shape[1]), np.int32)
+        toks[sel] = np.asarray(batch["tokens"])[:n]
+        at, rows = eng.pos.copy(), np.zeros((eng.batch,), np.int32)
+        at[sel], rows[sel] = np.asarray(pos)[:n], np.asarray(last)[:n]
+        full_tok, full = inner(params, {"tokens": jnp.asarray(toks)}, cache,
+                               jnp.asarray(at), jnp.asarray(rows))
+        tok, out = inner(params, batch, cache, pos, last, lanes)
+        log.append((np.asarray(lanes), np.asarray(tok),
+                    np.asarray(full_tok), *jax.device_get((cache, out, full))))
+        return tok, out
+
+    eng.prefill = prefill
+
+
+class TestLaneSelectedPrefill:
+    """On the paged cache each prefill call runs over only the lanes with
+    prompt rows in its chunk, at the smallest power-of-two width that
+    holds them.  Each call's tokens and its lanes' pages equal those of
+    the full-width call on the same inputs; every other page, the
+    decoding lanes' among them, is left bit-identical, and pad rows
+    write the trash page alone.  On the Pallas kernel (interpret mode)
+    and off it."""
+
+    B, CHUNK = 4, 8
+    #: per case: call widths of the admission under test
+    WIDTHS = {"beside_decoding": {1: 3},
+              "wave": {4: 2, 2: 1, 1: 1},
+              "wave_int8_pool": {4: 2, 2: 1, 1: 1},
+              "prefix_suffix": {2: 1, 1: 1}}
+
+    def _engine(self, kernel, **kw):
+        cfg, ctx, params, mesh = _setup("lm", "f32")
+        ctx = dataclasses.replace(ctx, force_paged_kernel=kernel)
+        return Engine(cfg, ctx, params, mesh, batch=self.B, max_len=48,
+                      paged=True, page_size=8, prefill_chunk=self.CHUNK,
+                      **kw)
+
+    @pytest.mark.parametrize("kernel", [False, True],
+                             ids=["einsum", "kernel"])
+    @pytest.mark.parametrize("case", list(WIDTHS))
+    def test_calls_equal_full_width(self, case, kernel):
+        cfg, _, _, mesh = _setup("lm", "f32")
+        with use_mesh(mesh):
+            eng = self._engine(kernel, prefix_cache=case == "prefix_suffix",
+                               kv_bits=8 if case.endswith("int8_pool")
+                               else None)
+            if case == "beside_decoding":
+                # two lanes decode while a 20-token prompt is admitted
+                eng.add_requests(dict(enumerate(_prompts(cfg, (12, 9)))),
+                                 gen_len=8)
+                eng.step_many(2)
+                reqs = {2: _prompts(cfg, (20,), seed=1)[0]}
+            elif case == "prefix_suffix":
+                # lane 1 ingests a 4-token suffix after a cached 16-token
+                # prefix and drops out of the second chunk, which lane
+                # 0's fresh 11-token prompt still has rows in
+                base = _prompts(cfg, (16,), seed=2)[0]
+                eng.add_requests({1: base}, gen_len=2)
+                eng.step_many(2)
+                eng.retire_finished()
+                reqs = {0: _prompts(cfg, (11,), seed=3)[0],
+                        1: np.concatenate([base, _prompts(cfg, (4,),
+                                                          seed=4)[0]])}
+            else:
+                # the shorter prompts end first: widths 4, 4 (one pad
+                # row), 2, 1
+                reqs = dict(enumerate(_prompts(cfg, (5, 13, 21, 30))))
+            decoding = np.flatnonzero(eng.live)
+            pos0 = eng.pos.copy()
+            pages0 = jax.device_get(eng.cache)["dense"]["pages"]
+            widths0 = dict(eng.counters["prefill_width_calls"])
+            log = []
+            _against_full_width(eng, log)
+            eng.add_requests(reqs, gen_len=2)
+        widths = {w: n - widths0.get(w, 0) for w, n in
+                  eng.counters["prefill_width_calls"].items()
+                  if n != widths0.get(w, 0)}
+        assert widths == self.WIDTHS[case]
+        trash = eng.allocator.num_pages
+        for lanes, tok, full_tok, c_in, c_out, c_full in log:
+            sel = lanes[lanes < self.B]
+            assert sel.tolist() == sorted(sel.tolist())
+            assert (lanes[sel.shape[0]:] == self.B).all()
+            assert lanes.shape[0] == eng._prefill_width(sel.shape[0])
+            np.testing.assert_array_equal(tok[:sel.shape[0]], full_tok[sel])
+            bt = c_in["dense"]["block_table"]
+            np.testing.assert_array_equal(c_out["dense"]["block_table"], bt)
+            mine = sorted(set(bt[0, sel].ravel().tolist()) - {trash})
+            rest = sorted(set(range(trash)) - set(mine))
+            for name, leaf in c_out["dense"]["pages"].items():
+                np.testing.assert_array_equal(
+                    leaf[:, mine], c_full["dense"]["pages"][name][:, mine])
+                np.testing.assert_array_equal(
+                    leaf[:, rest], c_in["dense"]["pages"][name][:, rest])
+        # the decoding lanes' pages and positions, across the admission
+        pages = jax.device_get(eng.cache)["dense"]["pages"]
+        for s in decoding:
+            assert eng.pos[s] == pos0[s]
+            own = sorted(set(eng.block_tables[s].tolist()) - {trash})
+            for name, leaf in pages.items():
+                np.testing.assert_array_equal(leaf[:, own],
+                                              pages0[name][:, own])
+        assert all(len(eng.outputs[s]) == 0 for s in reqs)
+        assert all(eng.live[s] for s in reqs)
+
+    def test_admissions_compile_no_prefill_program(self):
+        """The engine compiles its prefill program once per width (1, 2
+        and 4 lanes at a batch of 4) when it is built; admissions of 1
+        through 4 lanes, beside decoding lanes or into an idle batch,
+        compile none after that."""
+        cfg, _, _, mesh = _setup("lm", "f32")
+        with use_mesh(mesh):
+            eng = self._engine(False)
+            assert eng.prefill._cache_size() == 3
+            rs = np.random.RandomState(5)
+            for group in ((3,), (1, 2), (4,), (2, 2), (1, 1, 1, 1)):
+                for n in group:
+                    for _ in range(n):
+                        eng.submit(rs.randint(0, cfg.vocab,
+                                              (rs.randint(1, 30),)),
+                                   gen_len=int(rs.randint(1, 10)))
+                    eng.try_admit()
+                    eng.step_many(3)
+                    eng.retire_finished()
+            while eng.live.any() or eng.waiting:
+                eng.step_many(3)
+                eng.retire_finished()
+        assert set(eng.counters["prefill_width_calls"]) == {1, 2, 4}
+        assert eng.prefill._cache_size() == 3

@@ -105,6 +105,19 @@ def test_paged_attention_glm4_geometry(one_chip, s):
         *a, kv_split=16, pages_per_step=8), *args)
 
 
+@pytest.mark.parametrize("w", [1, 2, 4])
+def test_paged_attention_narrow_prefill(one_chip, w):
+    """A 128-token prefill chunk over the w lanes a narrow prefill call
+    selects, at glm4-9b's geometry (above)."""
+    args = (_spec((w, 32, 128, DH), jnp.bfloat16, one_chip),
+            _spec((3801, 2, PS, DH), jnp.float32, one_chip),
+            _spec((3801, 2, PS, DH), jnp.float32, one_chip),
+            _spec((w, 520), jnp.int32, one_chip),
+            _spec((w,), jnp.int32, one_chip))
+    assert _has_kernel(lambda *a: paged_attention_pallas(
+        *a, kv_split=16, pages_per_step=8), *args)
+
+
 def test_flash_attention(one_chip):
     q = _spec((1, HQ, 2048, DH), jnp.bfloat16, one_chip)
     kv = _spec((1, HKV, 2048, DH), jnp.bfloat16, one_chip)
